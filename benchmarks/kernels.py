@@ -3,7 +3,7 @@
 The NEP rows time the fused kernel pipeline stage by stage (K1
 descriptor+ANN+adjoints, the abar_j adjoint gather, K2 pair force/torque)
 through the mode-dispatched executor (``"auto"``: compiled Pallas on
-TPU/GPU, the compiled lax.map tiling on CPU), with jaxpr-level FLOPs and
+TPU, the compiled lax.map tiling on CPU), with jaxpr-level FLOPs and
 bytes per stage (repro.utils.jaxpr_cost) in the derived column - so both
 wall-clock AND op-count regressions of any single stage are visible.
 Attention/SSD rows time the *jnp* algorithmic variants (their Pallas
@@ -73,9 +73,9 @@ def bench_nep() -> list[str]:
     from repro.core.descriptor import NEPSpinSpec
     from repro.core.potential import energy_forces_field, init_params
     from repro.kernels.nep import resolve_mode
-    from repro.kernels.nep.kernel import (TILE_ATOMS, nep_atom_pass,
+    from repro.kernels.nep.kernel import (gather_abar, nep_atom_pass,
                                           nep_force_pass)
-    from repro.kernels.nep.ops import _pad_to, nep_energy_forces_field
+    from repro.kernels.nep.ops import nep_energy_forces_field
     from repro.launch.roofline import nep_measured
     from repro.md.lattice import b20_fege
     from repro.md.neighbor import dense_neighbor_table, gather_blocks
@@ -106,14 +106,10 @@ def bench_nep() -> list[str]:
     # (e.g. a K2 that re-runs accumulate per pair) are visible per stage
     nbh = gather_blocks(st.pos, st.types, tab, st.box)
     n = st.n_atoms
-    n_pad = -(-n // TILE_ATOMS) * TILE_ATOMS
     a = {
-        "dr": _pad_to(nbh.dr, n_pad), "mask": _pad_to(nbh.mask, n_pad),
-        "amask": _pad_to(jnp.ones((n,), bool), n_pad),
-        "ti": _pad_to(st.types, n_pad), "tj": _pad_to(nbh.tj, n_pad),
-        "si": _pad_to(st.spin, n_pad),
-        "sj": _pad_to(st.spin[nbh.idx], n_pad),
-        "idx": _pad_to(nbh.idx, n_pad),
+        "dr": nbh.dr, "mask": nbh.mask, "amask": jnp.ones((n,), bool),
+        "ti": st.types, "tj": nbh.tj, "si": st.spin,
+        "sj": st.spin[nbh.idx], "idx": nbh.idx,
     }
     cost = nep_measured(spec, params, nbh, st.spin, st.types, mode=mode)
 
@@ -122,7 +118,7 @@ def bench_nep() -> list[str]:
                 a["si"], a["sj"])
     _, _, abar = k1(a["dr"], a["mask"], a["amask"], a["ti"], a["tj"],
                     a["si"], a["sj"])
-    gather = jax.jit(lambda ab, ix: {k: v[ix] for k, v in ab.items()})
+    gather = jax.jit(gather_abar)
     tg = timeit(gather, abar, a["idx"])
     abar_j = gather(abar, a["idx"])
     k2 = jax.jit(partial(nep_force_pass, spec, params, mode=mode))

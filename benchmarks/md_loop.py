@@ -159,7 +159,7 @@ def main() -> list[str]:
     cases.append(("nep", lambda: NEPSpinPotential(spec, params), None))
     # fused NEP kernel path through the SAME fused loop (mode "auto":
     # compiled lax.map tiling on CPU; the identical kernel bodies compile
-    # to MXU Pallas kernels on TPU).  Tracked fused-only: its reference
+    # to Mosaic Pallas kernels on TPU).  Tracked fused-only: its reference
     # point is the autodiff fused path, so kernel-path regressions show up
     # as a vs_autodiff drift (gated >= 1.0 under --strict).
     cases.append(("nep_kernel", lambda: NEPSpinPotential(

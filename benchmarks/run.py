@@ -37,6 +37,8 @@ REGISTRY = ("kernels", "ablation", "throughput", "scaling", "accuracy",
 
 
 def main() -> None:
+    from repro.utils.compile_cache import use_compile_cache
+    use_compile_cache()
     argv = sys.argv[1:]
     if "--smoke" in argv:
         os.environ["BENCH_SMOKE"] = "1"

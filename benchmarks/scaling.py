@@ -4,12 +4,16 @@ Unlike the projection-only predecessor, this drives the real thing
 end-to-end: :class:`repro.md.simulate.SimulationSharded` - the shard_map
 domain-decomposed fused loop (in-scan rebuild + cell migration, one
 position halo per drift, adjoint-halo force fold-back) - on 1/2/4/8
-*simulated* host devices (``XLA_FLAGS=--xla_force_host_platform_device_
-count=N``), with a fixed per-device subdomain (weak scaling).
+devices, with a fixed per-device subdomain (weak scaling).
 
-Each device count runs in its OWN subprocess (the forced device count must
-be set before jax initializes); the parent collects per-worker JSON and
-emits ``BENCH_scaling.json`` with
+Under ``JAX_PLATFORMS=cpu`` the devices are *simulated* host devices
+(``XLA_FLAGS=--xla_force_host_platform_device_count=N``) and each device
+count runs in its OWN subprocess (the forced device count must be set
+before jax initializes).  On an accelerator host every count runs in this
+process over the first N of ``jax.devices()`` (a chip belongs to one
+process: a child of a parent that holds it could not reach it), up to the
+devices present.  The parent collects per-count JSON and emits
+``BENCH_scaling.json`` with
 
 * steps/s and weak-scaling efficiency vs the 1-device *flat* fused
   baseline (``Simulation`` at the same per-device atom count),
@@ -23,7 +27,7 @@ emits ``BENCH_scaling.json`` with
 
 Full (non-smoke) runs also record a ``nep_kernel`` entry: the fused
 NEP-SPIN kernel evaluator (``use_kernel=True``, mode "auto": compiled
-lax.map tiling on CPU, the identical bodies as MXU Pallas kernels on TPU)
+lax.map tiling on CPU, the identical bodies as Mosaic Pallas kernels on TPU)
 routed through the SAME sharded loop via the q_Fp adjoint-accumulator halo
 (``repro.parallel.domain.make_domain_kernel_evaluator``): steps/s on 2
 devices plus the exchange ledger, tracked so the kernel path through the
@@ -63,7 +67,9 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # worker: runs under a forced device count, prints one RESULT json line
 # ---------------------------------------------------------------------------
 
-def _worker(ndev: int, size: str, smoke: bool) -> None:
+def _worker(devices, size: str, smoke: bool) -> dict:
+    """Weak-scaling point on ``devices``: the sharded loop (and, on one
+    device, the flat fused baseline) at a fixed per-device subdomain."""
     import jax
     import jax.numpy as jnp
 
@@ -72,17 +78,11 @@ def _worker(ndev: int, size: str, smoke: bool) -> None:
     from repro.md.lattice import simple_cubic
     from repro.md.simulate import Simulation, SimulationSharded
     from repro.md.state import init_state
+    from repro.telemetry.metrics import CompileWatchdog
 
-    assert len(jax.devices()) == ndev, (len(jax.devices()), ndev)
+    ndev = len(devices)
     steps = CHUNK if smoke else 3 * CHUNK
-
-    compiles = {"n": 0}
-
-    def on_event(name, _dur, **kw):
-        if name == "/jax/core/compile/backend_compile_duration":
-            compiles["n"] += 1
-
-    jax.monitoring.register_event_duration_secs_listener(on_event)
+    watchdog = CompileWatchdog()
 
     lat = simple_cubic()
     per_dev = SIZES[size]
@@ -99,11 +99,11 @@ def _worker(ndev: int, size: str, smoke: bool) -> None:
     def timed(sim, warm_key, run_key):
         sim.run(CHUNK, warm_key, chunk=CHUNK)          # compile + warm
         jax.block_until_ready(sim.state.pos)
-        c0 = compiles["n"]
+        mark = watchdog.mark()
         t0 = time.perf_counter()
         sim.run(steps, run_key, chunk=CHUNK)
         jax.block_until_ready(sim.state.pos)
-        return (time.perf_counter() - t0, compiles["n"] - c0)
+        return (time.perf_counter() - t0, watchdog.since(mark))
 
     out = {"ndev": ndev, "size": size, "atoms": st.n_atoms,
            "atoms_per_device": st.n_atoms // ndev, "steps": steps}
@@ -113,7 +113,7 @@ def _worker(ndev: int, size: str, smoke: bool) -> None:
         wall, _ = timed(flat, jax.random.PRNGKey(1), jax.random.PRNGKey(2))
         out["flat_steps_per_s"] = steps / wall
 
-    sh = SimulationSharded(state=st, **kw)
+    sh = SimulationSharded(state=st, devices=tuple(devices), **kw)
     wall, n_comp = timed(sh, jax.random.PRNGKey(1), jax.random.PRNGKey(2))
     # one traced chunk covers warmup AND the measured run: counts are
     # per-step-body occurrences, bytes are per-device per occurrence;
@@ -136,10 +136,10 @@ def _worker(ndev: int, size: str, smoke: bool) -> None:
     })
     # the drift-exchange invariant of the gather->compute contract
     assert out["drift_pos_exchanges_per_step"] == 1, ledger.counts
-    print("RESULT " + json.dumps(out), flush=True)
+    return out
 
 
-def _worker_kernel(ndev: int, smoke: bool) -> None:
+def _worker_kernel(devices, smoke: bool) -> dict:
     """Pallas NEP kernel through the sharded loop (q_Fp halo route).
 
     Delegates to :func:`repro.launch.md_step.run_engine_chunk` - the same
@@ -147,16 +147,14 @@ def _worker_kernel(ndev: int, smoke: bool) -> None:
     benchmark and the human smoke cannot drift apart; this worker only
     adds the invariants and the RESULT line.
     """
-    import jax
-
     from repro.launch.md_step import run_engine_chunk
 
-    assert len(jax.devices()) == ndev, (len(jax.devices()), ndev)
+    ndev = len(devices)
     chunk = 2 if smoke else 5
     steps = chunk if smoke else 2 * chunk
     # y/z need >= 3 cells at cutoff+skin reach; x scales with the devices
     res = run_engine_chunk(cells=(4 * ndev, 6, 6), steps=steps,
-                           chunk=chunk, kernel=True)
+                           chunk=chunk, kernel=True, devices=devices)
     counts = res.pop("halo_counts")
     res.pop("halo_bytes")
     out = {
@@ -171,15 +169,34 @@ def _worker_kernel(ndev: int, smoke: bool) -> None:
     assert out["drift_pos_exchanges_per_step"] == 1, counts
     assert out["qfp_exchanges"] >= 1, counts
     assert "adjoint" not in counts, counts
-    print("RESULT " + json.dumps(out), flush=True)
+    return out
 
 
 # ---------------------------------------------------------------------------
-# parent: one subprocess per device count (XLA_FLAGS must precede jax init)
+# parent: one subprocess per simulated device count on CPU (XLA_FLAGS must
+# precede jax init), device subsets in this process on an accelerator
 # ---------------------------------------------------------------------------
+
+def _simulated() -> bool:
+    """CPU runs simulate their devices; decided without touching jax."""
+    return os.environ.get("JAX_PLATFORMS", "") == "cpu"
+
+
+def _device_counts(counts) -> tuple:
+    if _simulated():
+        return tuple(counts)
+    import jax
+    have = len(jax.devices())
+    return tuple(n for n in counts if n <= have) or (have,)
+
 
 def _run_worker(ndev: int, size: str, smoke: bool,
                 kernel: bool = False) -> dict:
+    if not _simulated():
+        import jax
+        devices = jax.devices()[:ndev]
+        return (_worker_kernel(devices, smoke) if kernel
+                else _worker(devices, size, smoke))
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
     if smoke:
@@ -202,7 +219,7 @@ def main() -> list[str]:
     from benchmarks.common import SMOKE, row
 
     rows = []
-    counts = SMOKE_DEVICES if SMOKE else DEVICE_COUNTS
+    counts = _device_counts(SMOKE_DEVICES if SMOKE else DEVICE_COUNTS)
     sizes = ("floor",) if SMOKE else tuple(SIZES)
     cores = os.cpu_count() or 1
     out = {"smoke": SMOKE, "potential": "heisenberg", "chunk": CHUNK,
@@ -246,10 +263,11 @@ def main() -> list[str]:
     if not SMOKE:
         # the fused NEP kernel through the SAME sharded loop (q_Fp halo);
         # smoke-sized spec, so only orchestration invariants are asserted
-        kres = _run_worker(2, "floor", SMOKE, kernel=True)
+        kres = _run_worker(min(2, max(counts)), "floor", SMOKE, kernel=True)
         out["nep_kernel"] = kres
         rows.append(row(
-            f"scaling/nep_kernel/sharded/ndev=2/N={kres['atoms']}",
+            f"scaling/nep_kernel/sharded/ndev={kres['devices']}/"
+            f"N={kres['atoms']}",
             1e6 / kres["steps_per_s"],
             f"{kres['steps_per_s']:.2f} steps/s|{kres['mode']}|"
             f"{kres['compiles_during_run']} compiles|"
@@ -283,14 +301,17 @@ def main() -> list[str]:
 
 if __name__ == "__main__":
     if "--worker" in sys.argv:
+        import jax
         ndev = int(sys.argv[sys.argv.index("--worker") + 1])
+        assert len(jax.devices()) == ndev, (len(jax.devices()), ndev)
         smoke = bool(os.environ.get("BENCH_SMOKE"))
         if "--kernel" in sys.argv:
-            _worker_kernel(ndev, smoke)
+            res = _worker_kernel(jax.devices(), smoke)
         else:
             size = (sys.argv[sys.argv.index("--size") + 1]
                     if "--size" in sys.argv else "floor")
-            _worker(ndev, size, smoke)
+            res = _worker(jax.devices(), size, smoke)
+        print("RESULT " + json.dumps(res), flush=True)
     else:
         print("name,us_per_call,derived")
         main()

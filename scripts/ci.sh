@@ -61,7 +61,10 @@ if [[ "${1:-}" == "--smoke" ]]; then
   env PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" \
       XLA_FLAGS="--xla_force_host_platform_device_count=2" \
       python scripts/engine_smoke.py
-  env PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" \
+  # CPU-only: the resilience and serve chaos smokes touch JAX in the
+  # parent and then start children that need a device, which a chip held
+  # by the parent would refuse (one process per chip)
+  env PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" JAX_PLATFORMS=cpu \
       python scripts/resilience_smoke.py
   # serving smoke: >=6 mixed-size jobs over >=2 shape buckets at f64 -
   # zero steady-state recompiles, packed-vs-solo bitwise parity, and a
@@ -71,7 +74,7 @@ if [[ "${1:-}" == "--smoke" ]]; then
   # serve chaos smoke: a child server dies by SIGKILL mid-fleet under a
   # seeded fault plan; the parent recovers from the durable job journal
   # and proves the remaining streams bitwise with zero steady recompiles
-  env PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" \
+  env PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" JAX_PLATFORMS=cpu \
       python scripts/serve_chaos_smoke.py
   # NEP kernel smoke: compiled dispatch (never interpret), oracle parity,
   # faster-than-interpret, and zero recompiles across chunked calls

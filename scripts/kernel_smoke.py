@@ -37,8 +37,7 @@ def main() -> None:
     mode = resolve_mode("auto")
     assert mode != "interpret", (
         f"auto dispatch resolved to interpret on {jax.default_backend()}")
-    expect = "pallas" if jax.default_backend() in ("tpu", "gpu") else \
-        "xla_tiled"
+    expect = "pallas" if jax.default_backend() == "tpu" else "xla_tiled"
     assert mode == expect, (mode, expect)
 
     spec = NEPSpinSpec(l_max=2, n_ang=2, n_rad=4, n_spin=2, basis_size=6)

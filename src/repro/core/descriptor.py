@@ -128,8 +128,7 @@ def _radial_g(coeffs: jax.Array, fk: jax.Array, ti: jax.Array,
     coeffs: (T, T, n, K); fk: (..., M, K); ti: (...,), tj: (..., M).
     Per-pair type selection is the vectorized-select analogue of the paper's
     predicated multi-type dispatch (svsel, Sec. 5-B3-ii): T^2 dense MXU
-    matmuls masked per lane - no type sorting, no gather/scatter, and it
-    lowers inside Pallas kernels (dynamic gathers do not).
+    matmuls masked per lane - no type sorting, no gather/scatter.
     Returns (..., M, n).
     """
     t = coeffs.shape[0]
@@ -218,8 +217,6 @@ def finalize(spec: NEPSpinSpec, acc: dict, si: jax.Array) -> jax.Array:
     mpow = {}
     for p in range(spec.l_max + 1):
         a2 = acc[f"ang{p}"] ** 2
-        # python-scalar weights: keeps the contraction free of captured
-        # constant arrays so finalize() can run inside Pallas kernel bodies
         mpow[p] = sum(w * a2[..., c] for c, (_, w) in enumerate(_MONO[p]))
     for l in range(1, spec.l_max + 1):
         feats.append(sum(coef * mpow[p] for p, coef in _LEGENDRE[l].items()))

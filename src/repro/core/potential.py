@@ -63,8 +63,7 @@ def mlp_energy(params: NEPSpinParams, q: jax.Array, ti: jax.Array) -> jax.Array:
     """Per-atom energy from descriptor q (N, D).
 
     Per-element weights via predicated dispatch: one dense (N,D)x(D,H) MXU
-    matmul per element type, masked per lane (the SME/svsel analogue; also
-    Pallas-lowerable, unlike a dynamic gather of weight tensors).
+    matmul per element type, masked per lane (the SME/svsel analogue).
     """
     qn = q / params.q_scale
     e = None
@@ -155,7 +154,7 @@ class NEPSpinPotential:
     ``use_kernel`` routes both through the fused kernels (repro.kernels.nep)
     instead of autodiff; ``mode`` selects the kernel executor ("pallas" |
     "xla_tiled" | "interpret"), with "auto" resolving per backend at trace
-    time (non-interpret Pallas on TPU/GPU, compiled lax.map tiling on CPU).
+    time (non-interpret Pallas on TPU, compiled lax.map tiling on CPU).
     """
 
     spec: NEPSpinSpec

@@ -18,7 +18,7 @@ gathering then computing.
 Both entry points take a static ``mode`` selecting the kernel executor
 (``"pallas"`` | ``"xla_tiled"`` | ``"interpret"``, see
 ``repro.kernels.nep.kernel``); the default ``"auto"`` resolves per backend
-at trace time - non-interpret Pallas on TPU/GPU, the compiled
+at trace time - non-interpret Pallas (Mosaic) on TPU, the compiled
 ``lax.map``-over-tiles path on CPU.  ``mode`` is part of the jit cache key,
 so chunked drivers that hold it fixed never recompile across chunks.
 """
@@ -31,19 +31,10 @@ import jax.numpy as jnp
 
 from repro.core.descriptor import NEPSpinSpec
 from repro.core.potential import NEPSpinParams
-from repro.kernels.nep.kernel import (TILE_ATOMS, acc_keys, nep_atom_pass,
+from repro.kernels.nep.kernel import (gather_abar, nep_atom_pass,
                                       nep_force_pass)
 from repro.md.neighbor import NeighborTable, Neighborhood, gather_blocks
 from repro.utils import units
-
-
-def _pad_to(x, n, axis=0):
-    pad = n - x.shape[axis]
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths)
 
 
 @partial(jax.jit, static_argnames=("spec", "mode"))
@@ -59,33 +50,18 @@ def nep_compute(
 ):
     """Fused-kernel (E, F, H_eff) from pre-gathered neighbor blocks."""
     n = spin.shape[0]
-    n_pad = -(-n // TILE_ATOMS) * TILE_ATOMS
-
     sj = spin[nbh.idx]
+    e, hdir, abar = nep_atom_pass(spec, params, nbh.dr, nbh.mask,
+                                  jnp.ones((n,), bool), types, nbh.tj, spin,
+                                  sj, mode=mode)
+    # gather neighbor adjoints (q_Fp exchange)
+    abar_j = gather_abar(abar, nbh.idx)
+    f, h2 = nep_force_pass(spec, params, nbh.dr, nbh.mask, types, nbh.tj,
+                           spin, sj, abar, abar_j, mode=mode)
 
-    amask = jnp.ones((n,), bool)
-    dr_p = _pad_to(nbh.dr, n_pad)
-    mask_p = _pad_to(nbh.mask, n_pad)
-    amask_p = _pad_to(amask, n_pad)
-    ti_p = _pad_to(types, n_pad)
-    tj_p = _pad_to(nbh.tj, n_pad)
-    si_p = _pad_to(spin, n_pad)
-    sj_p = _pad_to(sj, n_pad)
-
-    e, hdir, abar = nep_atom_pass(spec, params, dr_p, mask_p, amask_p,
-                                  ti_p, tj_p, si_p, sj_p, mode=mode)
-
-    # gather neighbor adjoints (q_Fp exchange). Table indices are < n and
-    # padded rows gather row 0 harmlessly (masked out in K2).
-    idx_p = _pad_to(nbh.idx, n_pad)
-    abar_j = {k: v[idx_p] for k, v in abar.items()}
-
-    f, h2 = nep_force_pass(spec, params, dr_p, mask_p, ti_p, tj_p, si_p,
-                           sj_p, abar, abar_j, mode=mode)
-
-    energy = jnp.sum(e[:n])
-    force = f[:n]
-    heff = hdir[:n] + h2[:n]
+    energy = jnp.sum(e)
+    force = f
+    heff = hdir + h2
     if field is not None:
         mom = moments[types] if moments is not None else jnp.ones((n,),
                                                                   spin.dtype)

@@ -301,6 +301,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
 
 def main():
+    from repro.utils.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -163,13 +163,14 @@ def _compile_counter() -> dict:
 
 def run_engine_chunk(cells=(8, 6, 6), steps: int = 40, chunk: int = 20,
                      temperature: float = 160.0, kernel: bool = False,
-                     seed: int = 0) -> dict:
-    """Drive one field-cooled chunk of the unified engine on the current
-    devices and return {steps_per_s, rebuilds, halo ledger, ...}.
+                     seed: int = 0, devices=None) -> dict:
+    """Drive one field-cooled chunk of the unified engine on ``devices``
+    (default: all of them) and return {steps_per_s, rebuilds, halo ledger,
+    ...}.
 
     ``kernel=True`` routes the fused NEP kernel evaluator through the
     sharded plan instead of the Heisenberg-DMI reference (mode "auto":
-    compiled Pallas on TPU/GPU, compiled lax.map tiling on CPU).
+    compiled Pallas on TPU, compiled lax.map tiling on CPU).
     """
     import time as _time
 
@@ -208,7 +209,8 @@ def run_engine_chunk(cells=(8, 6, 6), steps: int = 40, chunk: int = 20,
         potential=potential, cfg=icfg, state=st,
         masses=jnp.asarray(lat.masses, jnp.float32),
         magnetic=jnp.asarray(lat.moments) > 0, cutoff=5.0,
-        capacity=16, skin=0.3, plan=Sharded(),
+        capacity=16, skin=0.3,
+        plan=Sharded(devices=None if devices is None else tuple(devices)),
         temperature=temp, field=field,
         observables=("energy", "magnetization", "charge"))
     eng.run(chunk, jax.random.PRNGKey(1), chunk=chunk)   # compile + warm
@@ -219,7 +221,7 @@ def run_engine_chunk(cells=(8, 6, 6), steps: int = 40, chunk: int = 20,
     jax.block_until_ready(eng.state.pos)
     wall = _time.perf_counter() - t0
     return {
-        "devices": jax.device_count(),
+        "devices": eng._rplan.mesh.size,
         "atoms": st.n_atoms,
         "cells": tuple(eng._rplan.dspec.cells),
         "steps_per_s": steps / wall,
@@ -235,6 +237,8 @@ def run_engine_chunk(cells=(8, 6, 6), steps: int = 40, chunk: int = 20,
 
 
 def main():
+    from repro.utils.compile_cache import use_compile_cache
+    use_compile_cache()
     import argparse
 
     ap = argparse.ArgumentParser(description=__doc__)

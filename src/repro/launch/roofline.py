@@ -159,46 +159,33 @@ def nep_measured(spec, params, nbh, spin, types, mode: str = "auto") -> dict:
     """
     import jax
     import jax.numpy as jnp
-    from repro.kernels.nep.kernel import (TILE_ATOMS, nep_atom_pass,
-                                          nep_force_pass)
-    from repro.kernels.nep.ops import _pad_to
+    from repro.kernels.nep.kernel import (TILE_ATOMS, gather_abar,
+                                          nep_atom_pass, nep_force_pass)
     from repro.utils.jaxpr_cost import lowered_cost
 
     n = spin.shape[0]
     n_pad = -(-n // TILE_ATOMS) * TILE_ATOMS
-    sj = spin[nbh.idx]
-    amask = jnp.ones((n,), bool)
-    dr_p = _pad_to(nbh.dr, n_pad)
-    mask_p = _pad_to(nbh.mask, n_pad)
-    amask_p = _pad_to(amask, n_pad)
-    ti_p = _pad_to(types, n_pad)
-    tj_p = _pad_to(nbh.tj, n_pad)
-    si_p = _pad_to(spin, n_pad)
-    sj_p = _pad_to(sj, n_pad)
-    idx_p = _pad_to(nbh.idx, n_pad)
+    k1_args = (nbh.dr, nbh.mask, jnp.ones((n,), bool), types, nbh.tj, spin,
+               spin[nbh.idx])
 
     def k1_fn(dr, mask, am, ti, tj, si, sjv):
         return nep_atom_pass(spec, params, dr, mask, am, ti, tj, si, sjv,
                              mode=mode)
 
-    k1_cost = lowered_cost(jax.make_jaxpr(k1_fn)(
-        dr_p, mask_p, amask_p, ti_p, tj_p, si_p, sj_p))
-    _, _, abar = k1_fn(dr_p, mask_p, amask_p, ti_p, tj_p, si_p, sj_p)
+    k1_cost = lowered_cost(jax.make_jaxpr(k1_fn)(*k1_args))
+    _, _, abar = k1_fn(*k1_args)
 
-    def gather_fn(ab, ix):
-        return {k: v[ix] for k, v in ab.items()}
-
-    gather_cost = lowered_cost(jax.make_jaxpr(gather_fn)(abar, idx_p))
-    abar_j = gather_fn(abar, idx_p)
+    gather_cost = lowered_cost(jax.make_jaxpr(gather_abar)(abar, nbh.idx))
+    abar_j = gather_abar(abar, nbh.idx)
 
     def k2_fn(dr, mask, ti, tj, si, sjv, ab, abj):
         return nep_force_pass(spec, params, dr, mask, ti, tj, si, sjv,
                               ab, abj, mode=mode)
 
     k2_cost = lowered_cost(jax.make_jaxpr(k2_fn)(
-        dr_p, mask_p, ti_p, tj_p, si_p, sj_p, abar, abar_j))
+        nbh.dr, nbh.mask, types, nbh.tj, spin, spin[nbh.idx], abar, abar_j))
 
-    itemsize = jnp.dtype(dr_p.dtype).itemsize
+    itemsize = jnp.dtype(nbh.dr.dtype).itemsize
     row = nep_abar_row(spec)
     m = nbh.idx.shape[1]
     return {

@@ -72,6 +72,8 @@ def build_fleet(n_jobs: int, chunk: int, obs_every: int,
 
 
 def main(argv=None) -> int:
+    from repro.utils.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--jobs", type=int, default=8,
                     help="fleet size (default 8)")

@@ -38,6 +38,8 @@ def build_film(ecfg, seed: int = 0):
 
 
 def main() -> None:
+    from repro.utils.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--preset", default="smoke", choices=("smoke", "full"))
     ap.add_argument("--replicas", type=int, default=0)

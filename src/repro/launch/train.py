@@ -125,6 +125,8 @@ def train_md(args):
 
 
 def main():
+    from repro.utils.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)

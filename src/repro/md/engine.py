@@ -719,8 +719,11 @@ class Engine:
             return carry, obs, health_of(carry, etot0)
 
         self._chunk_fn = chunk
-        self._compute_ff = compute_ff
-        self._rebuild = rebuild
+        # the (re)start evaluation runs as one compiled program: dispatched
+        # op by op, every primitive of the rebuild and force call would be
+        # compiled on its own on an accelerator
+        self._compute_ff = jax.jit(compute_ff)
+        self._rebuild = jax.jit(rebuild)
         if farg is _UNSET:
             farg = self._norm_arg(self.field, vec=True)
         self._init_carry(table=self.table,

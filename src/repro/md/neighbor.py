@@ -300,9 +300,13 @@ def cell_neighbor_table(
     cand = cand.reshape(n, -1)                # (N, 27K)
     valid = cand >= 0
     cand_safe = jnp.where(valid, cand, 0)
-    dr = pos[cand_safe] - pos[:, None, :]
-    dr = dr - box * jnp.round(dr / box)
-    d2 = jnp.sum(dr * dr, axis=-1)
+    # per-component distances: an (N, 27K, 3) block would carry a minor
+    # dimension of 3, which a TPU pads to 128 lanes
+    d2 = None
+    for c in range(3):
+        d = pos[:, c][cand_safe] - pos[:, c:c + 1]
+        d = d - box[c] * jnp.round(d / box[c])
+        d2 = d * d if d2 is None else d2 + d * d
     good = valid & (d2 <= rc * rc) & (cand != jnp.arange(n)[:, None])
     neg = jnp.where(good, -d2, -jnp.inf)
     k = min(capacity, neg.shape[1])

@@ -296,6 +296,7 @@ class SimulationSharded:
     cell_capacity: int | None = None   # per-cell capacity K (None -> auto)
     mesh: Any = None                   # jax Mesh (None -> 1D over devices)
     axis_map: tuple = None             # spatial dim -> mesh axis name
+    devices: tuple | None = None       # subset for the auto-built 1D mesh
     halo_mode: str = "auto"            # "ppermute" | "allgather" | "auto"
     field: jax.Array | None = None     # (3,) Tesla (or (R, 3) w/ replicas)
     replicas: int = 0                  # 0 = no replica axis
@@ -308,6 +309,7 @@ class SimulationSharded:
             potential=self.potential, cfg=self.cfg, state=self.state,
             masses=self.masses, magnetic=self.magnetic, cutoff=self.cutoff,
             plan=Sharded(mesh=self.mesh, axis_map=self.axis_map,
+                         devices=self.devices,
                          halo_mode=self.halo_mode, cells=self.cells,
                          cell_capacity=self.cell_capacity,
                          replicas=self.replicas,

@@ -70,10 +70,8 @@ def apply_moe(cfg, p, x):
     expert-parallel path when a production mesh is active."""
     impl = getattr(cfg, "moe_impl", "auto")
     if impl != "dense":
-        from repro.parallel.sharding import _current_mesh
-        mesh = _current_mesh()
-        if mesh is not None and not mesh.empty and "model" in \
-                mesh.axis_names:
+        mesh = jax.sharding.get_abstract_mesh()
+        if not mesh.empty and "model" in mesh.axis_names:
             t = x.shape[0] * x.shape[1]
             n_all = 1
             for a in mesh.axis_names:

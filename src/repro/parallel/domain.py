@@ -478,8 +478,8 @@ def distributed_energy_fn_pruned(spec, dspec, mesh, capacity=64,
 # per-atom adjoints with its 26 neighbors (one extra halo round), gathers
 # neighbor adjoints through the same pruned table, and runs K2 - forces and
 # torques come out pair-symmetric with NO reverse force scatter.
-# ``mode`` selects the kernel executor (repro.kernels.nep.kernel): on TPU/
-# GPU the pallas_call compiles to MXU kernels; on CPU "auto" resolves to
+# ``mode`` selects the kernel executor (repro.kernels.nep.kernel): on TPU
+# the pallas_call compiles to Mosaic kernels; on CPU "auto" resolves to
 # the compiled lax.map tiling ("xla_tiled"); "interpret" remains the slow
 # per-ref debugging oracle.
 
@@ -489,14 +489,13 @@ def distributed_kernel_force_fn(spec, dspec, mesh, capacity=64,
     signatures of distributed_energy_fn_pruned, but evaluated with the
     fused Pallas kernels instead of autodiff."""
     from jax.sharding import PartitionSpec as P
-    from repro.kernels.nep.kernel import (TILE_ATOMS, acc_keys,
-                                          nep_atom_pass, nep_force_pass)
+    from repro.kernels.nep.kernel import (gather_abar, nep_atom_pass,
+                                          nep_force_pass)
     from repro.parallel.halo import exchange_halo
 
     mom = moments if moments is not None else jnp.ones((max(spec.n_types,
                                                             1),))
     cell = dspec.pspec
-    keys = acc_keys(spec)
 
     build = shard_map_compat(
         partial(build_domain_table, spec, dspec, capacity), mesh,
@@ -506,9 +505,6 @@ def distributed_kernel_force_fn(spec, dspec, mesh, capacity=64,
     def body(params, pos, spin, types, mask, tbl_idx, tbl_mask):
         cx, cy, cz, k = mask.shape
         n_loc = cx * cy * cz * k
-        assert n_loc % TILE_ATOMS == 0, (
-            f"local atoms {n_loc} not a multiple of TILE_ATOMS "
-            f"{TILE_ATOMS}")
         m_cap = tbl_idx.shape[-1]
         dtype = pos.dtype
         box = jnp.asarray(dspec.box, dtype)
@@ -536,12 +532,10 @@ def distributed_kernel_force_fn(spec, dspec, mesh, capacity=64,
                                       tj, si, sj, mode=mode)
 
         # q_Fp exchange: adjoints of ghosts via one extra halo round
-        abar_j = {}
-        for kk in keys:
-            tail = abar[kk].shape[1:]
-            cell_arr = abar[kk].reshape(cx, cy, cz, k, *tail)
-            ext = exchange_halo(cell_arr, dspec.axis_map)
-            abar_j[kk] = ext.reshape(-1, *tail)[idx_f]
+        n_rows = abar.shape[0]
+        ext = exchange_halo(abar.T.reshape(cx, cy, cz, k, n_rows),
+                            dspec.axis_map)
+        abar_j = gather_abar(ext.reshape(-1, n_rows).T, idx_f)
 
         # K2: fused pair-symmetric force + torque (one neighbor pass)
         f, h2 = nep_force_pass(spec, params, dr, msk_f, ti, tj, si, sj,
@@ -812,8 +806,10 @@ def make_domain_refresh(dspec: DomainSpec,
                                      local_wrap)
     from repro.parallel.overlap import issue_early, shell_slabs
 
-    # the issue-early optimization barrier has no vmap rule on jax 0.4.x,
-    # so the replica-batched loop runs without the scheduling hint
+    # the engine turns the issue-early scheduling hint off on the
+    # replica-batched loop (barrier=False): whether the hint helps once the
+    # exchange is vmapped over replicas has not been measured, so that
+    # path keeps the schedule XLA picks on its own
     early = issue_early if barrier else (lambda x: x)
     axis_map = dspec.axis_map
     slabs = shell_slabs(local_shape)
@@ -903,8 +899,10 @@ def make_domain_evaluator(potential, dspec: DomainSpec,
                                      fold_halo_multi, local_wrap)
     from repro.parallel.overlap import issue_early, shell_slabs
 
-    # the issue-early optimization barrier has no vmap rule on jax 0.4.x,
-    # so the replica-batched loop runs without the scheduling hint
+    # the engine turns the issue-early scheduling hint off on the
+    # replica-batched loop (barrier=False): whether the hint helps once the
+    # exchange is vmapped over replicas has not been measured, so that
+    # path keeps the schedule XLA picks on its own
     early = issue_early if barrier else (lambda x: x)
     axis_map = dspec.axis_map
     slabs = shell_slabs(local_shape)
@@ -1044,9 +1042,9 @@ def make_domain_kernel_evaluator(potential, dspec: DomainSpec,
     self-consistent midpoint configs): ``compute`` consumes the ``dr`` AND
     ``sj`` blocks refreshed by the drift exchange.  The kernel executor
     comes from ``potential.mode``: "auto" resolves to non-interpret Pallas
-    on TPU/GPU (MXU kernels) and to the compiled lax.map tiling on CPU.
+    on TPU (Mosaic kernels) and to the compiled lax.map tiling on CPU.
     """
-    from repro.kernels.nep.kernel import (TILE_ATOMS, nep_atom_pass,
+    from repro.kernels.nep.kernel import (gather_abar, nep_atom_pass,
                                           nep_force_pass)
     from repro.parallel.halo import exchange_halo_multi
 
@@ -1064,15 +1062,7 @@ def make_domain_kernel_evaluator(potential, dspec: DomainSpec,
         occ = types >= 0
         ti = jnp.where(occ, types, 0)
         n_slots = cx * cy * cz * k
-        n_pad = -(-n_slots // TILE_ATOMS) * TILE_ATOMS
-
-        def pad0(a):
-            extra = n_pad - n_slots
-            if not extra:
-                return a
-            return jnp.pad(a, [(0, extra)] + [(0, 0)] * (a.ndim - 1))
-
-        flat = lambda a, tail: pad0(a.reshape((n_slots,) + tail))
+        flat = lambda a, tail: a.reshape((n_slots,) + tail)
         dr_f = flat(nbh.dr, (m_cap, 3))
         mask_f = flat(nbh.mask, (m_cap,))
         occ_f = flat(occ, ())
@@ -1082,29 +1072,25 @@ def make_domain_kernel_evaluator(potential, dspec: DomainSpec,
         sj_f = flat(nbh.sj, (m_cap, 3))
 
         # K1: energy + direct field + adjoint accumulators (empty slots
-        # and pad rows are amask-zeroed, so they contribute nothing here
-        # or through the exchange below)
+        # are amask-zeroed, so they contribute nothing here or through the
+        # exchange below)
         e, hdir, abar = nep_atom_pass(spec, params, dr_f, mask_f, occ_f,
                                       ti_f, tj_f, si_f, sj_f, mode=mode)
 
         # the q_Fp exchange: ONE fused halo of every Abar channel
-        abar_blk = {kk: v[:n_slots].reshape((cx, cy, cz, k) + v.shape[1:])
-                    for kk, v in abar.items()}
-        ext = exchange_halo_multi(abar_blk, axis_map, tag="qfp",
-                                  allgather=allgather)
-        idx_f = nbh.idx.reshape(-1)          # (n_slots*M,) ext-flat slots
-        abar_j = {}
-        for kk, v in ext.items():
-            tail = v.shape[4:]
-            g = v.reshape((-1,) + tail)[idx_f]
-            abar_j[kk] = pad0(g.reshape((n_slots, m_cap) + tail))
+        n_rows = abar.shape[0]
+        abar_blk = abar.T.reshape((cx, cy, cz, k, n_rows))
+        ext = exchange_halo_multi({"abar": abar_blk}, axis_map, tag="qfp",
+                                  allgather=allgather)["abar"]
+        idx_f = nbh.idx.reshape(n_slots, m_cap)  # ext-flat slots
+        abar_j = gather_abar(ext.reshape(-1, n_rows).T, idx_f)
 
         # K2: fused force + torque, no reverse scatter
         f, h2 = nep_force_pass(spec, params, dr_f, mask_f, ti_f, tj_f,
                                si_f, sj_f, abar, abar_j, mode=mode)
         e_loc = jnp.sum(e)                   # masked rows are exact zeros
-        force = f[:n_slots].reshape(types.shape + (3,))
-        heff = (hdir + h2)[:n_slots].reshape(types.shape + (3,))
+        force = f.reshape(types.shape + (3,))
+        heff = (hdir + h2).reshape(types.shape + (3,))
         if field is not None:
             mom = jnp.where(occ, potential.site_moments(ti), 0.0)
             fld = jnp.asarray(field, dtype)

@@ -96,25 +96,12 @@ def resolve_spec(mesh, logical: tuple, shape: tuple[int, ...],
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``shard_map`` portable across jax versions.
-
-    jax >= 0.5 exposes ``jax.shard_map`` (replication checking via
-    ``check_vma``); 0.4.x only has ``jax.experimental.shard_map.shard_map``
-    (``check_rep``).  Replication checking is disabled either way: the
+    """``jax.shard_map`` with replication checking off: the
     domain-decomposed MD code mixes per-device values (halo ghosts, local
     tables) with replicated scalars, which the checker cannot express.
     """
-    smfn = getattr(jax, "shard_map", None)
-    if smfn is not None:
-        try:
-            return smfn(f, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return smfn(f, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 _ACTIVE_MODE = ["tp"]
@@ -125,24 +112,10 @@ def set_mode(mode: str):
     _ACTIVE_MODE[0] = mode
 
 
-def _current_mesh():
-    """The active mesh, portable across jax versions: the abstract mesh
-    (jax >= 0.5) when available, else the `with Mesh(...)` physical-mesh
-    context (jax 0.4.x); None when neither is set."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        return get_abstract()
-    try:
-        from jax._src.mesh import thread_resources
-        return thread_resources.env.physical_mesh
-    except Exception:
-        return None
-
-
 def shard(x: jax.Array, *logical) -> jax.Array:
     """Activation sharding constraint; no-op when no mesh is active."""
-    mesh = _current_mesh()
-    if mesh is None or mesh.empty or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or not mesh.axis_names:
         return x
     spec = resolve_spec(mesh, logical, x.shape, _ACTIVE_MODE[0])
     return jax.lax.with_sharding_constraint(x, spec)

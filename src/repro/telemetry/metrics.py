@@ -25,7 +25,7 @@ import dataclasses
 # ---------------------------------------------------------------------------
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_WATCHDOG = {"count": 0, "registered": False}
+_WATCHDOG = {"count": 0, "seconds": 0.0, "registered": False}
 
 
 def _ensure_listener() -> None:
@@ -36,6 +36,7 @@ def _ensure_listener() -> None:
     def _on_event(event: str, duration: float, **kw) -> None:
         if event == _COMPILE_EVENT:
             _WATCHDOG["count"] += 1
+            _WATCHDOG["seconds"] += duration
 
     monitoring.register_event_duration_secs_listener(_on_event)
     _WATCHDOG["registered"] = True
@@ -51,6 +52,11 @@ class CompileWatchdog:
     def count(self) -> int:
         """Total backend compiles observed in this process so far."""
         return _WATCHDOG["count"]
+
+    @property
+    def seconds(self) -> float:
+        """Total backend compile wall time [s] in this process so far."""
+        return _WATCHDOG["seconds"]
 
     def mark(self) -> int:
         """Take a mark; pass it to :meth:`since` for a run-scoped delta."""

@@ -12,14 +12,13 @@ Two complementary levels:
 
 :func:`maybe_trace` wraps a run in ``jax.profiler`` start/stop when given
 a dump directory (``Telemetry.profile_dir``), producing a
-perfetto-loadable trace; with ``None`` it is a no-op, and profiler
-start-up failures degrade to a warning (some backends/sandboxes cannot
-profile - a run must never die because its profiler could not).
+perfetto-loadable trace; with ``None`` it is a no-op.  A requested trace
+that cannot start or stop raises: a run that asked for a trace never ends
+as a success without one.
 """
 from __future__ import annotations
 
 import contextlib
-import warnings
 
 
 def phase(name: str):
@@ -41,23 +40,25 @@ def annotate(name: str):
 
 @contextlib.contextmanager
 def maybe_trace(profile_dir: str | None):
-    """Dump a perfetto-loadable profiler trace to ``profile_dir`` (opt-in)."""
+    """Dump a perfetto-loadable profiler trace to ``profile_dir`` (opt-in).
+
+    Raises ``RuntimeError`` when the trace cannot start or stop."""
     if not profile_dir:
         yield
         return
     import jax.profiler
 
-    started = False
     try:
         jax.profiler.start_trace(str(profile_dir))
-        started = True
-    except Exception as exc:    # pragma: no cover - backend dependent
-        warnings.warn(f"profiler trace unavailable: {exc}", stacklevel=2)
+    except Exception as exc:
+        raise RuntimeError(
+            f"profiler trace could not start in {profile_dir}: {exc}") from exc
     try:
         yield
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception as exc:   # pragma: no cover
-                warnings.warn(f"profiler stop failed: {exc}", stacklevel=2)
+        try:
+            jax.profiler.stop_trace()
+        except Exception as exc:
+            raise RuntimeError(
+                f"profiler trace could not stop in {profile_dir}: {exc}"
+            ) from exc

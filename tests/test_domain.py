@@ -5,17 +5,7 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
-
-# The distributed path drives the explicit-mesh APIs (jax.set_mesh,
-# jax.sharding.AxisType, make_mesh(axis_types=...)).  On older jax (< 0.5)
-# those don't exist and the subprocess would die in setup with an opaque
-# AttributeError - skip the whole module cleanly instead.
-pytestmark = pytest.mark.skipif(
-    not (hasattr(jax, "set_mesh") and hasattr(jax.sharding, "AxisType")),
-    reason="needs jax >= 0.5 explicit-mesh APIs (jax.set_mesh, "
-           "jax.sharding.AxisType) for the multi-device domain path")
 
 _SCRIPT = r"""
 import os

@@ -88,7 +88,7 @@ def test_kernel_energy_translation_invariant():
 
 def test_auto_mode_resolves_compiled():
     assert resolve_mode("auto") == (
-        "pallas" if jax.default_backend() in ("tpu", "gpu") else "xla_tiled")
+        "pallas" if jax.default_backend() == "tpu" else "xla_tiled")
     assert resolve_mode("interpret") == "interpret"
     with pytest.raises(ValueError):
         resolve_mode("fast")
@@ -134,7 +134,7 @@ def test_xla_tiled_lax_map_grouping():
     spec = NEPSpinSpec(l_max=2, n_ang=2, n_rad=3, n_spin=2, basis_size=5,
                        n_types=1)
     params = init_params(spec, jax.random.PRNGKey(7), dtype=jnp.float32)
-    n, m = 18 * TILE_ATOMS, 6     # 18 tiles: rows=9*64, 2 lax.map steps
+    n, m = 18 * TILE_ATOMS, 6     # 18 tiles: 6 per lax.map step, 3 steps
     ks = jax.random.split(jax.random.PRNGKey(11), 4)
     dr = jax.random.uniform(ks[0], (n, m, 3), jnp.float32, -2.5, 2.5)
     mask = jax.random.bernoulli(ks[1], 0.8, (n, m))
@@ -149,8 +149,7 @@ def test_xla_tiled_lax_map_grouping():
                                sj, mode="xla_tiled")
     np.testing.assert_allclose(e1, e0, rtol=2e-5, atol=1e-6)
     np.testing.assert_allclose(h1, h0, rtol=2e-5, atol=1e-5)
-    for k in a0:
-        np.testing.assert_allclose(a1[k], a0[k], rtol=2e-5, atol=1e-5)
+    np.testing.assert_allclose(a1, a0, rtol=2e-5, atol=1e-5)
 
 
 def test_single_compile_across_chunked_calls():
