@@ -34,6 +34,7 @@ from repro.core.potential import NEPSpinParams
 from repro.kernels.nep.kernel import (gather_abar, nep_atom_pass,
                                       nep_force_pass)
 from repro.md.neighbor import NeighborTable, Neighborhood, gather_blocks
+from repro.telemetry.profiling import phase
 from repro.utils import units
 
 
@@ -50,14 +51,18 @@ def nep_compute(
 ):
     """Fused-kernel (E, F, H_eff) from pre-gathered neighbor blocks."""
     n = spin.shape[0]
-    sj = spin[nbh.idx]
-    e, hdir, abar = nep_atom_pass(spec, params, nbh.dr, nbh.mask,
-                                  jnp.ones((n,), bool), types, nbh.tj, spin,
-                                  sj, mode=mode)
+    with phase("force.spins"):
+        sj = spin[nbh.idx]
+    with phase("force.atom_pass"):
+        e, hdir, abar = nep_atom_pass(spec, params, nbh.dr, nbh.mask,
+                                      jnp.ones((n,), bool), types, nbh.tj,
+                                      spin, sj, mode=mode)
     # gather neighbor adjoints (q_Fp exchange)
-    abar_j = gather_abar(abar, nbh.idx)
-    f, h2 = nep_force_pass(spec, params, nbh.dr, nbh.mask, types, nbh.tj,
-                           spin, sj, abar, abar_j, mode=mode)
+    with phase("force.adjoint"):
+        abar_j = gather_abar(abar, nbh.idx)
+    with phase("force.force_pass"):
+        f, h2 = nep_force_pass(spec, params, nbh.dr, nbh.mask, types,
+                               nbh.tj, spin, sj, abar, abar_j, mode=mode)
 
     energy = jnp.sum(e)
     force = f

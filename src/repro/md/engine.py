@@ -48,6 +48,7 @@ class (kept for their established constructor/trace surfaces).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from functools import partial
 from typing import Any, Callable, NamedTuple
@@ -60,15 +61,16 @@ from repro.md.analysis import (accumulate_spin_grid, accumulate_spin_profile,
                                charge_from_grid, helix_pitch, magnetization,
                                pitch_from_profile, skyrmion_count,
                                topological_charge)
-from repro.md.integrator import ForceField, IntegratorConfig, make_fused_step
+from repro.md.integrator import (ForceField, IntegratorConfig,
+                                 force_calls_per_step, make_fused_step)
 from repro.md.neighbor import (NeighborTable, Neighborhood, cell_order,
                                gather_blocks, make_table_builder,
                                needs_rebuild, refresh_dr)
 from repro.md.state import SpinLatticeState, kinetic_energy
 from repro.parallel.halo import HaloTrace
 from repro.parallel.plan import Replicated, Sharded, SingleDevice, as_plan
-from repro.telemetry import (TelemetrySession, as_telemetry, check_chunk,
-                             maybe_trace, phase)
+from repro.telemetry import (RunMetrics, TelemetrySession, annotate,
+                             as_telemetry, check_chunk, phase)
 from repro.telemetry.monitor import (HealthError, nonfinite_count,
                                      occupancy_fraction, spin_norm_dev)
 from repro.utils import units
@@ -348,6 +350,46 @@ def _permute_atoms(state: SpinLatticeState, order) -> SpinLatticeState:
                           spin=state.spin[order], types=state.types[order])
 
 
+def _refresh(nbh: Neighborhood, pos, box) -> Neighborhood:
+    """The step's one position gather after the drift."""
+    with phase("integrate.refresh"):
+        return refresh_dr(nbh, pos, box)
+
+
+def _within(scope, name: str):
+    """Decorate a function to run inside ``scope(name)``: a device phase
+    (:func:`~repro.telemetry.profiling.phase`) or a host span
+    (:func:`~repro.telemetry.profiling.annotate`)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
+
+
+# the compiled chunk of every plan runs under ``repro.loop``, so that its
+# ops outside every step phase (the scan loop itself, the half-skin test,
+# the schedule lookup, the health signals) carry a scope too; the phases
+# nest beneath it and stay innermost
+_loop_scope = _within(phase, "loop")
+
+
+@jax.jit
+def _flat_observation(c: FusedCarry):
+    """A cell-ordered flat carry in original atom order, as one program:
+    (state, forces, table)."""
+    with phase("sync"):
+        inv = jnp.argsort(c.perm)
+        ff = ForceField(energy=c.ff.energy, force=c.ff.force[inv],
+                        field=c.ff.field[inv])
+        table = NeighborTable(idx=c.perm[c.table.idx[inv]],
+                              mask=c.table.mask[inv], r0=c.table.r0[inv],
+                              cutoff=c.table.cutoff)
+        return _permute_atoms(c.state, inv), ff, table
+
+
 # vmap axis spec for a replica-shared Neighborhood: table-static blocks are
 # unbatched (one copy for all replicas), dr is replica-batched
 _NBH_AXES = Neighborhood(idx=None, mask=None, tj=None, dr=0)
@@ -448,6 +490,9 @@ class Engine:
         self.run_tags = {}           # extra run_start header fields (the
                                      # serving layer tags segments with
                                      # their bucket id for accounting)
+        self._counts = RunMetrics(counters=dict.fromkeys(
+            ("chunks", "steps", "restarts", "restart_builds",
+             "force_calls"), 0))   # counters() adds the carry's rebuilds
         self.plan = as_plan(self.plan)
         self.observables = _check_names(self.observables)
         if self.obs_every is not None and self.obs_every < 1:
@@ -493,6 +538,31 @@ class Engine:
     @property
     def n_rebuilds(self) -> int:
         return int(self._carry.n_rebuilds)
+
+    def counters(self) -> dict:
+        """The program's own counts since construction; a window's are the
+        difference of two reads.
+
+        ``chunks`` and ``steps`` run; ``restarts``, carries (re)built from
+        ``state`` (construction included); ``restart_builds``, the table
+        builds those ran; ``rebuilds``, the in-scan builds as the carry
+        counts them; ``force_calls``, force evaluations: each step's static
+        count (:func:`~repro.md.integrator.force_calls_per_step`), one per
+        in-scan build, one per restart, one per ``write_slots`` (a batched
+        evaluation of the replica plan counts once).  Reads the carry's
+        build count: one device-to-host transfer per call, none per chunk.
+        """
+        out = {k: int(v) for k, v in self._counts.counters.items()}
+        out["rebuilds"] = self.n_rebuilds
+        out["force_calls"] += out["rebuilds"]
+        return out
+
+    def _count_restart(self, built: bool) -> None:
+        """A carry (re)built from ``state``: one force evaluation, and one
+        table build unless ``built`` is False."""
+        self._counts.inc("restarts")
+        self._counts.inc("restart_builds", int(built))
+        self._counts.inc("force_calls")
 
     @property
     def energy(self):
@@ -657,16 +727,20 @@ class Engine:
             """In-graph: (re)order atoms, rebuild table, gather, evaluate."""
             with phase("rebuild"):
                 if reorder:
-                    order = cell_order(state.pos, state.box, n_cells)
-                    state = _permute_atoms(state, order)
-                    perm = perm[order]
+                    with phase("rebuild.order"):
+                        order = cell_order(state.pos, state.box, n_cells)
+                        state = _permute_atoms(state, order)
+                        perm = perm[order]
                 table = build(state.pos, state.box)
-                nbh = gather_blocks(state.pos, state.types, table, state.box)
-            ff = compute_ff(nbh, state.spin, state.types, field)
+                with phase("rebuild.gather"):
+                    nbh = gather_blocks(state.pos, state.types, table,
+                                        state.box)
+            with phase("force.after_build"):
+                ff = compute_ff(nbh, state.spin, state.types, field)
             return state, ff, table, nbh, perm
 
         step = make_fused_step(
-            gather=lambda pos, nbh: refresh_dr(nbh, pos, box0),
+            gather=lambda pos, nbh: _refresh(nbh, pos, box0),
             compute=compute_ff, cfg=self.cfg, masses=masses,
             magnetic=magnetic)
 
@@ -688,6 +762,7 @@ class Engine:
         # schedule arguments are runtime pytrees (their structure - absent /
         # constant / knots - keys the jit cache; their VALUES never retrace)
         @partial(jax.jit, static_argnames=("n", "emit"))
+        @_loop_scope
         def chunk(carry: FusedCarry, key, targ, farg, n: int, emit):
             t0 = carry.state.step.astype(jnp.float32) * dt
             etot0 = carry.ff.energy + kinetic_energy(carry.state, masses)
@@ -738,12 +813,13 @@ class Engine:
         """
         if self.state is self._obs_state:
             return
-        if np.array_equal(np.asarray(self.state.box),
-                          np.asarray(self._carry.state.box)):
-            self._init_carry(field_now=self._value_now(farg, vec=True))
-        else:
-            self.table = None
-            self._setup_flat(farg)
+        with annotate("repro.restart"):
+            if np.array_equal(np.asarray(self.state.box),
+                              np.asarray(self._carry.state.box)):
+                self._init_carry(field_now=self._value_now(farg, vec=True))
+            else:
+                self.table = None
+                self._setup_flat(farg)
 
     def _init_carry(self, table: NeighborTable | None = None,
                     field_now=None):
@@ -765,6 +841,7 @@ class Engine:
             st, ff, tab, nbh, perm = self._rebuild(self.state, perm0,
                                                    field_now)
             self._carry = FusedCarry(st, ff, tab, nbh, perm, count0)
+        self._count_restart(built=table is None)
         self._sync_observation()
 
     def _sync_flat(self):
@@ -775,18 +852,12 @@ class Engine:
         (``potential.energy_forces_field(..., table, ...)``) stays
         consistent with ``engine.state``.
         """
-        c = self._carry
-        inv = jnp.argsort(c.perm)
-        self.state = _permute_atoms(c.state, inv)
-        self._ff = ForceField(energy=c.ff.energy, force=c.ff.force[inv],
-                              field=c.ff.field[inv])
         if self._reorder:
-            self.table = NeighborTable(idx=c.perm[c.table.idx[inv]],
-                                       mask=c.table.mask[inv],
-                                       r0=c.table.r0[inv],
-                                       cutoff=c.table.cutoff)
-        else:
-            self.table = c.table
+            self.state, self._ff, self.table = _flat_observation(
+                self._carry)
+        else:   # rows are in atom order: the identity permutation
+            c = self._carry
+            self.state, self._ff, self.table = c.state, c.ff, c.table
         self._obs_state = self.state
 
     # ==================================================================
@@ -832,15 +903,18 @@ class Engine:
             """Rebuild the shared table + per-replica dr / forces."""
             with phase("rebuild"):
                 table = build(reference_pos(states), box0)
-                nbh = shared_blocks(table, states.pos)
+                with phase("rebuild.gather"):
+                    nbh = shared_blocks(table, states.pos)
             f_ax = None if field_r is None else 0
-            ffs = jax.vmap(
-                lambda d, s, f: compute_ff(nbh._replace(dr=d), s, types0, f),
-                in_axes=(0, 0, f_ax))(nbh.dr, states.spin, field_r)
+            with phase("force.after_build"):
+                ffs = jax.vmap(
+                    lambda d, s, f: compute_ff(nbh._replace(dr=d), s,
+                                               types0, f),
+                    in_axes=(0, 0, f_ax))(nbh.dr, states.spin, field_r)
             return table, nbh, ffs
 
         step = make_fused_step(
-            gather=lambda pos, nbh: refresh_dr(nbh, pos, box0),
+            gather=lambda pos, nbh: _refresh(nbh, pos, box0),
             compute=compute_ff, cfg=self.cfg, masses=masses,
             magnetic=magnetic)
 
@@ -880,6 +954,7 @@ class Engine:
             return h
 
         @partial(jax.jit, static_argnames=("n", "emit"))
+        @_loop_scope
         def chunk(carry: ReplicaCarry, key, targ, farg, n: int, emit):
             # per_slot: every slot keeps its own clock (R,) so backfilled
             # jobs evaluate their schedules at their own elapsed time
@@ -952,6 +1027,7 @@ class Engine:
                              self._replica_put(f0), nbh)
         self._carry = ReplicaCarry(self.state, ffs, table, nbh,
                                    jnp.asarray(0, jnp.int32))
+        self._count_restart(built=self.table is None)
         self._sync_observation()
 
     def _replica_restart_if_swapped(self, farg):
@@ -960,7 +1036,8 @@ class Engine:
         carry must flow through unchanged so checkpoint resume stays
         bitwise."""
         if self.state is not self._obs_state:
-            self._replica_resync(farg)
+            with annotate("repro.restart"):
+                self._replica_resync(farg)
 
     def _replica_resync(self, farg):
         """Explicit resync: honor caller-nudged states (sub-half-skin
@@ -976,6 +1053,7 @@ class Engine:
         ffs = self._vcompute(nbh.dr, c.states.spin, self._replica_put(f),
                              nbh)
         self._carry = c._replace(nbh=nbh, ffs=ffs)
+        self._count_restart(built=False)
         self._obs_state = self.state
 
     def shard_replicas(self, devices=None) -> "Engine":
@@ -1083,6 +1161,7 @@ class Engine:
         ffs = jax.tree_util.tree_map(
             lambda cur, row: cur.at[idx].set(row), c.ffs, ffs_rows)
         self._carry = c._replace(states=new_states, nbh=nbh, ffs=ffs)
+        self._counts.inc("force_calls")
         self._sync_observation()
 
     # ==================================================================
@@ -1212,7 +1291,8 @@ class Engine:
                               tag="rebuild-pos")
                 state = state._replace(pos=pos, vel=vel, spin=spin,
                                        types=types)
-            ff = compute_ff(nbh, spin, types, field)
+            with phase("force.after_build"):
+                ff = compute_ff(nbh, spin, types, field)
             return state, ff, nbh, aid, pos, moved, dropped
 
         step = make_fused_step(
@@ -1287,6 +1367,7 @@ class Engine:
                     / float(k_cap)),
             }
 
+        @_loop_scope
         def local_chunk(carry: DomainCarry, key, targ, farg, n: int, emit):
             t0 = carry.state.step.astype(jnp.float32) * dt
             etot0 = etot_of(carry)
@@ -1486,6 +1567,7 @@ class Engine:
         args = [put(a, s) for a, s in zip(args, in_specs)]
         with self._halo:
             self._carry = init(*args)
+        self._count_restart(built=True)
         self._check_dropped()
         self._sync_observation()
 
@@ -1549,6 +1631,7 @@ class Engine:
     # ==================================================================
     # observation, run loop, checkpoint
     # ==================================================================
+    @_within(annotate, "repro.sync")
     def _sync_observation(self):
         if isinstance(self.plan, SingleDevice):
             self._sync_flat()
@@ -1557,6 +1640,7 @@ class Engine:
         else:
             self._sync_domain()
 
+    @_within(annotate, "repro.run")
     def run(self, n_steps: int, key: jax.Array, chunk: int = 20, *,
             temperature=_UNSET, field=_UNSET,
             callback: Callable[["Engine"], None] | None = None,
@@ -1580,10 +1664,13 @@ class Engine:
         runlog, health signals are checked against the config's
         thresholds at every chunk boundary (raising a structured
         :class:`~repro.telemetry.monitor.HealthError` that names the
-        last-good checkpoint), and an optional ``profile_dir`` dumps a
-        perfetto trace.  Health signals are computed on every run either
-        way and land in ``self.trace.health``; only the checking and
-        persistence are opt-in.
+        last-good checkpoint).  Health signals are computed on every run
+        either way and land in ``self.trace.health``; only the checking and
+        persistence are opt-in.  A ``jax.profiler`` trace the caller opens
+        around the call holds the run's host spans (``repro.run``,
+        ``repro.restart``, ``repro.chunk`` and its parts, ``repro.sync``,
+        ``repro.checkpoint``, ``repro.callback``) beside the device ops of
+        its named phases; :meth:`counters` gives its counts.
 
         ``key`` is a single ``(2,)`` PRNG key - except on a ``per_slot``
         Replicated plan, where it must be a per-slot ``(R, 2)`` stack:
@@ -1623,10 +1710,8 @@ class Engine:
                 tel, ledger=self._halo,
                 run_info=self._run_info(n_steps, chunk))
         try:
-            with maybe_trace(tel.profile_dir if tel is not None else None):
-                self._run_loop(n_steps, key, chunk, targ, farg, callback,
-                               checkpoint_dir, checkpoint_every, tel,
-                               session)
+            self._run_loop(n_steps, key, chunk, targ, farg, callback,
+                           checkpoint_dir, checkpoint_every, tel, session)
         except BaseException as exc:
             if session is not None:
                 session.finish(status="failed", error=str(exc))
@@ -1655,111 +1740,134 @@ class Engine:
 
     def _run_loop(self, n_steps, key, chunk, targ, farg, callback,
                   checkpoint_dir, checkpoint_every, tel, session) -> None:
+        """The chunk loop.  Each chunk runs under the host span
+        ``repro.chunk`` (its index as metadata) with the parts
+        ``.lower`` (key split, schedule rows), ``.enqueue`` (the call into
+        the compiled chunk), ``.wait`` (where the host blocks on its
+        observables and health) and ``.gate`` (health gate, runlog)."""
         carry = self._carry
         t0 = float(self._step_now()) * self.cfg.dt
         rows, times, hrows = [], [], []
         done = 0
         chunks_done = 0
-        reb_prev = int(np.asarray(carry.n_rebuilds))
-        mig_prev = (int(np.asarray(carry.n_migrated))
-                    if isinstance(self.plan, Sharded) else 0)
+        calls = force_calls_per_step(self.cfg)
+        if session is not None:
+            reb_prev = int(np.asarray(carry.n_rebuilds))
+            mig_prev = (int(np.asarray(carry.n_migrated))
+                        if isinstance(self.plan, Sharded) else 0)
         while done < n_steps:
-            n = min(chunk, n_steps - done)
-            emit = self._emit_for(n)
-            if self._fault_injector is not None:
-                # resilience hook: host-side carry corruption at the chunk
-                # boundary (repro.resilience.faults); keeps self._carry in
-                # sync so step accounting sees the injected carry
-                carry = self._fault_injector(self, carry, n)
-                self._carry = carry
-            key, sub = self._split_key(key)
-            if isinstance(self.plan, Replicated):
-                sub = self._replica_put(sub)
-            # schedules lower to host-evaluated per-step rows HERE, with
-            # the live carry's clock(s) - see _chunk_arg for why this
-            # cannot happen inside the compiled chunk
-            targ_c = self._chunk_arg(targ, carry, n)
-            farg_c = self._chunk_arg(farg, carry, n)
-            t_chunk = time.perf_counter()
-            with self._halo:     # run-scoped ledger catches chunk traces
-                if isinstance(self.plan, Sharded):
-                    fn = self._chunk_for(n, emit, targ_c, farg_c)
-                    args = [carry, sub]
-                    if targ_c is not None:
-                        args.append(targ_c)
-                    if farg_c is not None:
-                        args.append(farg_c)
-                    carry, obs, health = fn(*args)
+            with annotate("repro.chunk", chunk=chunks_done):
+                n = min(chunk, n_steps - done)
+                emit = self._emit_for(n)
+                if self._fault_injector is not None:
+                    # resilience hook: host-side carry corruption at the
+                    # chunk boundary (repro.resilience.faults); keeps
+                    # self._carry in sync so step accounting sees the
+                    # injected carry
+                    carry = self._fault_injector(self, carry, n)
+                    self._carry = carry
+                with annotate("repro.chunk.lower"):
+                    key, sub = self._split_key(key)
+                    if isinstance(self.plan, Replicated):
+                        sub = self._replica_put(sub)
+                    # schedules lower to host-evaluated per-step rows HERE,
+                    # with the live carry's clock(s) - see _chunk_arg for
+                    # why this cannot happen inside the compiled chunk
+                    targ_c = self._chunk_arg(targ, carry, n)
+                    farg_c = self._chunk_arg(farg, carry, n)
+                t_chunk = time.perf_counter()
+                # the run-scoped ledger catches chunk traces
+                with annotate("repro.chunk.enqueue"), self._halo:
+                    if isinstance(self.plan, Sharded):
+                        fn = self._chunk_for(n, emit, targ_c, farg_c)
+                        args = [carry, sub]
+                        if targ_c is not None:
+                            args.append(targ_c)
+                        if farg_c is not None:
+                            args.append(farg_c)
+                        carry, obs, health = fn(*args)
+                    else:
+                        carry, obs, health = self._chunk_fn(
+                            carry, sub, targ_c, farg_c, n, emit)
+                if emit is None:
+                    times.append(t0 + (done + n) * self.cfg.dt)
                 else:
-                    carry, obs, health = self._chunk_fn(carry, sub, targ_c,
-                                                        farg_c, n, emit)
-            if emit is None:
-                times.append(t0 + (done + n) * self.cfg.dt)
-            else:
-                times.extend(t0 + (done + i + 1) * self.cfg.dt
-                             for i in emit)
-            rows.append(jax.tree_util.tree_map(np.asarray, obs))
-            # per_slot health carries (R,) attribution vectors alongside
-            # the gating scalars - keep vectors as lists (JSON-able)
-            h_host = {k: (np.asarray(v).tolist() if np.asarray(v).ndim
-                          else np.asarray(v).item())
-                      for k, v in health.items()}
-            hrows.append(h_host)
-            wall = time.perf_counter() - t_chunk  # np.asarray blocked above
-            done += n
-            chunks_done += 1
-            self._carry = carry
+                    times.extend(t0 + (done + i + 1) * self.cfg.dt
+                                 for i in emit)
+                with annotate("repro.chunk.wait"):
+                    rows.append(jax.tree_util.tree_map(np.asarray, obs))
+                    # per_slot health carries (R,) attribution vectors
+                    # alongside the gating scalars - keep vectors as lists
+                    # (JSON-able)
+                    h_host = {k: (np.asarray(v).tolist()
+                                  if np.asarray(v).ndim
+                                  else np.asarray(v).item())
+                              for k, v in health.items()}
+                hrows.append(h_host)
+                wall = time.perf_counter() - t_chunk  # .wait blocked
+                done += n
+                chunks_done += 1
+                self._carry = carry
+                self._counts.inc("chunks")
+                self._counts.inc("steps", n)
+                self._counts.inc("force_calls", n * calls)
 
-            # health gate BEFORE checkpointing: a failing chunk must not
-            # become the newest checkpoint (abort-and-resume contract)
-            verdict, err = "ok", None
-            try:
-                if isinstance(self.plan, Sharded):
-                    self._check_dropped(chunk_index=chunks_done - 1)
-                if tel is not None and tel.health is not None:
-                    verdict = check_chunk(
-                        h_host, tel.health, step=self._step_now(),
-                        chunk_index=chunks_done - 1,
-                        checkpoint_path=self._last_ckpt)
-            except HealthError as e:
-                verdict, err = "fail", e
-            if session is not None:
-                reb = int(np.asarray(carry.n_rebuilds))
-                counters = {"rebuilds": reb - reb_prev}
-                reb_prev = reb
-                if isinstance(self.plan, Sharded):
-                    mig = int(np.asarray(carry.n_migrated))
-                    counters["migrations"] = mig - mig_prev
-                    mig_prev = mig
-                session.chunk(
-                    steps=n, step=self._step_now(),
-                    time_ps=t0 + done * self.cfg.dt, wall_s=wall,
-                    health=h_host, verdict=verdict,
-                    chunk_cache=self._chunk_cache_size(),
-                    counters=counters,
-                    error=None if err is None else str(err))
-            if err is not None:
-                self._fold_trace(rows, times, hrows)
-                raise err
-            if checkpoint_dir is not None and (
-                    chunks_done % checkpoint_every == 0 or done >= n_steps):
-                self.save(checkpoint_dir, key=key)
-            if callback is not None:
-                self._sync_observation()
-                callback(self)
-                if isinstance(self.plan, SingleDevice):
-                    self._restart_if_swapped(farg)  # callback may perturb
-                elif isinstance(self.plan, Replicated):
-                    self._replica_restart_if_swapped(farg)
-                elif self.state is not self._obs_state:
-                    # repacking the cell-major layout mid-run is not
-                    # wired up; dropping the swap silently would be worse
-                    raise NotImplementedError(
-                        "state swaps from a callback are not supported on "
-                        "the Sharded plan (callbacks are observation-only "
-                        "there); build a new Engine from the modified "
-                        "state instead")
-                carry = self._carry
+                # health gate BEFORE checkpointing: a failing chunk must not
+                # become the newest checkpoint (abort-and-resume contract)
+                with annotate("repro.chunk.gate"):
+                    verdict, err = "ok", None
+                    try:
+                        if isinstance(self.plan, Sharded):
+                            self._check_dropped(chunk_index=chunks_done - 1)
+                        if tel is not None and tel.health is not None:
+                            verdict = check_chunk(
+                                h_host, tel.health, step=self._step_now(),
+                                chunk_index=chunks_done - 1,
+                                checkpoint_path=self._last_ckpt)
+                    except HealthError as e:
+                        verdict, err = "fail", e
+                    if session is not None:
+                        reb = int(np.asarray(carry.n_rebuilds))
+                        counters = {"rebuilds": reb - reb_prev}
+                        reb_prev = reb
+                        if isinstance(self.plan, Sharded):
+                            mig = int(np.asarray(carry.n_migrated))
+                            counters["migrations"] = mig - mig_prev
+                            mig_prev = mig
+                        session.chunk(
+                            steps=n, step=self._step_now(),
+                            time_ps=t0 + done * self.cfg.dt, wall_s=wall,
+                            health=h_host, verdict=verdict,
+                            chunk_cache=self._chunk_cache_size(),
+                            counters=counters,
+                            error=None if err is None else str(err))
+                if err is not None:
+                    self._fold_trace(rows, times, hrows)
+                    raise err
+                if checkpoint_dir is not None and (
+                        chunks_done % checkpoint_every == 0
+                        or done >= n_steps):
+                    with annotate("repro.checkpoint"):
+                        self.save(checkpoint_dir, key=key)
+                if callback is not None:
+                    with annotate("repro.callback"):
+                        self._sync_observation()
+                        callback(self)
+                        # the callback may perturb the state
+                        if isinstance(self.plan, SingleDevice):
+                            self._restart_if_swapped(farg)
+                        elif isinstance(self.plan, Replicated):
+                            self._replica_restart_if_swapped(farg)
+                        elif self.state is not self._obs_state:
+                            # repacking the cell-major layout mid-run is
+                            # not wired up; dropping the swap silently
+                            # would be worse
+                            raise NotImplementedError(
+                                "state swaps from a callback are not "
+                                "supported on the Sharded plan (callbacks "
+                                "are observation-only there); build a new "
+                                "Engine from the modified state instead")
+                    carry = self._carry
         self._carry = carry
         self._sync_observation()
         self._fold_trace(rows, times, hrows)

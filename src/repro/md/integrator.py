@@ -69,6 +69,13 @@ class IntegratorConfig:
     frozen_lattice: bool = False
 
 
+def force_calls_per_step(cfg: IntegratorConfig) -> int:
+    """Force evaluations one fused step makes (:func:`make_fused_step`):
+    one after the drift, plus ``midpoint_iters`` in each spin half-step
+    when the self-consistent midpoint update is on."""
+    return 1 + 2 * cfg.midpoint_iters if cfg.midpoint else 1
+
+
 class ForceField(NamedTuple):
     """Output of one fused potential evaluation."""
     energy: jax.Array  # ()

@@ -44,6 +44,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.telemetry.profiling import phase
+
 
 class NeighborTable(NamedTuple):
     idx: jax.Array    # (N, M) int32 neighbor indices (self-padded where invalid)
@@ -65,22 +67,24 @@ def dense_neighbor_table(
     Selects up to ``capacity`` nearest neighbors inside cutoff+skin per atom
     (distance-sorted, so truncation drops the farthest ones).
     """
-    n = pos.shape[0]
-    rc = cutoff + skin
-    dr = pos[None, :, :] - pos[:, None, :]
-    dr = dr - box * jnp.round(dr / box)
-    d2 = jnp.sum(dr * dr, axis=-1)
-    d2 = d2.at[jnp.arange(n), jnp.arange(n)].set(jnp.inf)  # exclude self
-    within = d2 <= rc * rc
-    # distance-sorted top-k selection (paper: cutoff filter + packing)
-    neg = jnp.where(within, -d2, -jnp.inf)
-    vals, idx = jax.lax.top_k(neg, min(capacity, n))
-    mask = vals > -jnp.inf
-    if idx.shape[1] < capacity:  # pad columns if capacity > n
-        pad = capacity - idx.shape[1]
-        idx = jnp.pad(idx, ((0, 0), (0, pad)))
-        mask = jnp.pad(mask, ((0, 0), (0, pad)))
-    idx = jnp.where(mask, idx, jnp.arange(n)[:, None])  # self-pad invalid slots
+    with phase("rebuild.search"):
+        n = pos.shape[0]
+        rc = cutoff + skin
+        dr = pos[None, :, :] - pos[:, None, :]
+        dr = dr - box * jnp.round(dr / box)
+        d2 = jnp.sum(dr * dr, axis=-1)
+        d2 = d2.at[jnp.arange(n), jnp.arange(n)].set(jnp.inf)  # exclude self
+        within = d2 <= rc * rc
+        # distance-sorted top-k selection (paper: cutoff filter + packing)
+        neg = jnp.where(within, -d2, -jnp.inf)
+        vals, idx = jax.lax.top_k(neg, min(capacity, n))
+        mask = vals > -jnp.inf
+        if idx.shape[1] < capacity:  # pad columns if capacity > n
+            pad = capacity - idx.shape[1]
+            idx = jnp.pad(idx, ((0, 0), (0, pad)))
+            mask = jnp.pad(mask, ((0, 0), (0, pad)))
+        # self-pad invalid slots
+        idx = jnp.where(mask, idx, jnp.arange(n)[:, None])
     return NeighborTable(idx=idx.astype(jnp.int32), mask=mask,
                          r0=pos, cutoff=jnp.asarray(rc))
 
@@ -154,8 +158,12 @@ def compute_from_blocks(etot, nbh: Neighborhood, spin: jax.Array):
     field as -dE/dS.  Both shipped potentials' ``compute`` methods route
     through this so the force-assembly convention cannot diverge.
     """
-    e, (g_dr, g_s) = jax.value_and_grad(etot, argnums=(0, 1))(nbh.dr, spin)
-    return e, assemble_pair_forces(g_dr, nbh), -g_s
+    with phase("force.energy_grad"):
+        e, (g_dr, g_s) = jax.value_and_grad(etot, argnums=(0, 1))(nbh.dr,
+                                                                   spin)
+    with phase("force.assemble"):
+        f = assemble_pair_forces(g_dr, nbh)
+    return e, f, -g_s
 
 
 def assemble_pair_forces(g_dr: jax.Array, nbh: Neighborhood) -> jax.Array:
@@ -246,21 +254,24 @@ def bin_atoms(pos: jax.Array, box: jax.Array, n_cells: tuple[int, int, int],
     and flagged (callers must size capacity so overflow never fires; tests
     assert the flag).
     """
-    cx, cy, cz = n_cells
-    *_, flat = _cell_coords(pos, box, n_cells)
-    n = pos.shape[0]
-    # rank of each atom within its cell via sort
-    order = jnp.argsort(flat, stable=True)
-    sorted_flat = flat[order]
-    # position within run of equal cell ids
-    idx_in_run = jnp.arange(n) - jnp.searchsorted(sorted_flat, sorted_flat, side="left")
-    slot = jnp.zeros(n, jnp.int32).at[order].set(idx_in_run.astype(jnp.int32))
-    overflow = jnp.any(slot >= capacity)
-    slot_c = jnp.minimum(slot, capacity - 1)
-    grid = jnp.full((cx * cy * cz * capacity,), -1, jnp.int32)
-    grid = grid.at[flat * capacity + slot_c].set(
-        jnp.where(slot < capacity, jnp.arange(n, dtype=jnp.int32), -1))
-    grid = grid.reshape(cx, cy, cz, capacity)
+    with phase("rebuild.bin"):
+        cx, cy, cz = n_cells
+        *_, flat = _cell_coords(pos, box, n_cells)
+        n = pos.shape[0]
+        # rank of each atom within its cell via sort
+        order = jnp.argsort(flat, stable=True)
+        sorted_flat = flat[order]
+        # position within run of equal cell ids
+        idx_in_run = jnp.arange(n) - jnp.searchsorted(
+            sorted_flat, sorted_flat, side="left")
+        slot = jnp.zeros(n, jnp.int32).at[order].set(
+            idx_in_run.astype(jnp.int32))
+        overflow = jnp.any(slot >= capacity)
+        slot_c = jnp.minimum(slot, capacity - 1)
+        grid = jnp.full((cx * cy * cz * capacity,), -1, jnp.int32)
+        grid = grid.at[flat * capacity + slot_c].set(
+            jnp.where(slot < capacity, jnp.arange(n, dtype=jnp.int32), -1))
+        grid = grid.reshape(cx, cy, cz, capacity)
     return grid, grid >= 0, overflow
 
 
@@ -288,36 +299,37 @@ def cell_neighbor_table(
     cx, cy, cz = n_cells
     grid, gmask, _ = bin_atoms(pos, box, n_cells, cell_capacity)
     n = pos.shape[0]
-    ci, cj, ck, _ = _cell_coords(pos, box, n_cells)
+    with phase("rebuild.search"):
+        ci, cj, ck, _ = _cell_coords(pos, box, n_cells)
 
-    # candidates: 27 stencil cells x cell_capacity
-    offs = jnp.array([(a, b, c) for a in (-1, 0, 1) for b in (-1, 0, 1)
-                      for c in (-1, 0, 1)], dtype=jnp.int32)  # (27,3)
-    sci = (ci[:, None] + offs[None, :, 0]) % cx
-    scj = (cj[:, None] + offs[None, :, 1]) % cy
-    sck = (ck[:, None] + offs[None, :, 2]) % cz
-    cand = grid[sci, scj, sck]                # (N, 27, K)
-    cand = cand.reshape(n, -1)                # (N, 27K)
-    valid = cand >= 0
-    cand_safe = jnp.where(valid, cand, 0)
-    # per-component distances: an (N, 27K, 3) block would carry a minor
-    # dimension of 3, which a TPU pads to 128 lanes
-    d2 = None
-    for c in range(3):
-        d = pos[:, c][cand_safe] - pos[:, c:c + 1]
-        d = d - box[c] * jnp.round(d / box[c])
-        d2 = d * d if d2 is None else d2 + d * d
-    good = valid & (d2 <= rc * rc) & (cand != jnp.arange(n)[:, None])
-    neg = jnp.where(good, -d2, -jnp.inf)
-    k = min(capacity, neg.shape[1])
-    vals, sel = jax.lax.top_k(neg, k)
-    mask = vals > -jnp.inf
-    idx = jnp.take_along_axis(cand_safe, sel, axis=1)
-    idx = jnp.where(mask, idx, jnp.arange(n)[:, None])
-    if k < capacity:
-        idx = jnp.pad(idx, ((0, 0), (0, capacity - k)),
-                      constant_values=0)
-        idx = idx.at[:, k:].set(jnp.arange(n)[:, None])
-        mask = jnp.pad(mask, ((0, 0), (0, capacity - k)))
+        # candidates: 27 stencil cells x cell_capacity
+        offs = jnp.array([(a, b, c) for a in (-1, 0, 1) for b in (-1, 0, 1)
+                          for c in (-1, 0, 1)], dtype=jnp.int32)  # (27,3)
+        sci = (ci[:, None] + offs[None, :, 0]) % cx
+        scj = (cj[:, None] + offs[None, :, 1]) % cy
+        sck = (ck[:, None] + offs[None, :, 2]) % cz
+        cand = grid[sci, scj, sck]                # (N, 27, K)
+        cand = cand.reshape(n, -1)                # (N, 27K)
+        valid = cand >= 0
+        cand_safe = jnp.where(valid, cand, 0)
+        # per-component distances: an (N, 27K, 3) block would carry a minor
+        # dimension of 3, which a TPU pads to 128 lanes
+        d2 = None
+        for c in range(3):
+            d = pos[:, c][cand_safe] - pos[:, c:c + 1]
+            d = d - box[c] * jnp.round(d / box[c])
+            d2 = d * d if d2 is None else d2 + d * d
+        good = valid & (d2 <= rc * rc) & (cand != jnp.arange(n)[:, None])
+        neg = jnp.where(good, -d2, -jnp.inf)
+        k = min(capacity, neg.shape[1])
+        vals, sel = jax.lax.top_k(neg, k)
+        mask = vals > -jnp.inf
+        idx = jnp.take_along_axis(cand_safe, sel, axis=1)
+        idx = jnp.where(mask, idx, jnp.arange(n)[:, None])
+        if k < capacity:
+            idx = jnp.pad(idx, ((0, 0), (0, capacity - k)),
+                          constant_values=0)
+            idx = idx.at[:, k:].set(jnp.arange(n)[:, None])
+            mask = jnp.pad(mask, ((0, 0), (0, capacity - k)))
     return NeighborTable(idx=idx.astype(jnp.int32), mask=mask,
                          r0=pos, cutoff=jnp.asarray(rc))

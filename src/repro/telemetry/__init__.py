@@ -9,8 +9,8 @@ Four pieces, threaded through ``Engine.run(telemetry=...)`` on all plans:
   threshold checks, and the structured :class:`HealthError` that carries
   the last-good checkpoint path.
 * :mod:`repro.telemetry.profiling` - ``named_scope`` phase markers inside
-  the compiled step, host ``TraceAnnotation``, and an opt-in
-  ``jax.profiler`` perfetto dump directory.
+  the compiled step and host ``TraceAnnotation`` spans around the run
+  loop; they land in whatever ``jax.profiler`` trace the caller opens.
 * :mod:`repro.telemetry.runlog` - the per-chunk JSONL event stream that
   ``launch/report.py`` renders and the planner/serving layers consume.
 
@@ -33,14 +33,14 @@ from repro.telemetry.metrics import (CompileWatchdog, RunMetrics,
 from repro.telemetry.monitor import (HealthConfig, HealthError, check_chunk,
                                      nonfinite_count, occupancy_fraction,
                                      spin_norm_dev)
-from repro.telemetry.profiling import annotate, maybe_trace, phase
+from repro.telemetry.profiling import annotate, phase
 from repro.telemetry.runlog import RunLog, append_event, read_runlog
 
 __all__ = [
     "Telemetry", "TelemetrySession", "RunMetrics", "CompileWatchdog",
     "HealthConfig", "HealthError", "RunLog", "read_runlog", "append_event",
     "check_chunk", "nonfinite_count", "occupancy_fraction", "spin_norm_dev",
-    "phase", "annotate", "maybe_trace", "peak_device_memory", "as_telemetry",
+    "phase", "annotate", "peak_device_memory", "as_telemetry",
 ]
 
 
@@ -48,23 +48,22 @@ __all__ = [
 class Telemetry:
     """Run observability config handed to ``Engine.run(telemetry=...)``.
 
-    One object bundles the three opt-in surfaces of a monitored run:
+    One object bundles the two opt-in surfaces of a monitored run:
     the JSONL ``runlog`` (per-chunk throughput, compile-watchdog deltas,
     halo-ledger bytes, drift, health verdict - the machine-readable
     record ``repro.launch.report`` renders and the serving accounting
-    replays), the ``health`` thresholds checked at every chunk boundary
-    (raising :class:`HealthError`; ``None`` disables checking, signals
-    are still computed into ``engine.trace.health``), and an optional
-    perfetto ``profile_dir``.  ``append=True`` continues an existing
-    runlog instead of truncating it - retry segments and packed serving
-    segments share one file that way.  A bare path passed to
+    replays) and the ``health`` thresholds checked at every chunk
+    boundary (raising :class:`HealthError`; ``None`` disables checking,
+    signals are still computed into ``engine.trace.health``).
+    ``append=True`` continues an existing runlog instead of truncating
+    it - retry segments and packed serving segments share one file that
+    way.  A bare path passed to
     ``Engine.run`` is shorthand for ``Telemetry(runlog=path)``
     (:func:`as_telemetry`)."""
 
     runlog: str | os.PathLike | None = None    # JSONL event stream path
     health: HealthConfig | None = dataclasses.field(
         default_factory=HealthConfig)          # None disables checking
-    profile_dir: str | os.PathLike | None = None   # perfetto dump dir
     metrics: RunMetrics = dataclasses.field(default_factory=RunMetrics)
     append: bool = False     # append to an existing runlog (retry segments)
 

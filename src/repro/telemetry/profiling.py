@@ -1,20 +1,24 @@
-"""Profiler hooks: phase scopes, host annotations, perfetto trace dumps.
+"""Profiler hooks: device phase scopes and host spans.
 
-Two complementary levels:
+Two complementary levels, both landing in one ``jax.profiler`` trace on
+one clock:
 
 * :func:`phase` - ``jax.named_scope`` wrapper used *inside* jitted code
   (engine step phases, halo exchanges).  Zero runtime cost: it only names
-  the HLO ops, so XLA profiles and dumped traces attribute time to
-  ``repro.force`` / ``repro.halo.spin`` / ... instead of ``fusion.1234``.
+  the HLO ops, so XLA profiles attribute time to ``repro.force`` /
+  ``repro.halo.spin`` / ... instead of ``fusion.1234``.  A sub-phase is a
+  dotted name nested under its phase (``repro.rebuild`` /
+  ``repro.rebuild.search``), so the innermost ``repro.<phase>`` of an op
+  stays the phase it belongs to.
 * :func:`annotate` - ``jax.profiler.TraceAnnotation`` for *host-side*
-  regions (chunk dispatch, checkpoint writes); shows up on the Python
-  track of a profiler trace.
+  regions (``repro.run``, ``repro.chunk`` and its parts, checkpoint
+  writes); shows up on the Python track of a profiler trace, so an idle
+  gap of the device can be put down to what the host was doing.
 
-:func:`maybe_trace` wraps a run in ``jax.profiler`` start/stop when given
-a dump directory (``Telemetry.profile_dir``), producing a
-perfetto-loadable trace; with ``None`` it is a no-op.  A requested trace
-that cannot start or stop raises: a run that asked for a trace never ends
-as a success without one.
+Nothing here starts the profiler: an operator traces a window with
+``jax.profiler.trace(dir)`` (or ``start_trace`` / ``stop_trace``) around
+their own ``Engine.run`` calls, and these scopes and spans land in that
+trace.
 """
 from __future__ import annotations
 
@@ -28,37 +32,13 @@ def phase(name: str):
     return jax.named_scope(f"repro.{name}")
 
 
-def annotate(name: str):
-    """Host-side profiler annotation (runtime region on the Python track)."""
+def annotate(name: str, **metadata):
+    """Host-side profiler annotation (runtime region on the Python track);
+    ``metadata`` lands in the span's arguments.  Costs about a microsecond
+    while no trace is open."""
     try:
         import jax.profiler
 
-        return jax.profiler.TraceAnnotation(name)
+        return jax.profiler.TraceAnnotation(name, **metadata)
     except Exception:           # profiler unavailable: degrade to no-op
         return contextlib.nullcontext()
-
-
-@contextlib.contextmanager
-def maybe_trace(profile_dir: str | None):
-    """Dump a perfetto-loadable profiler trace to ``profile_dir`` (opt-in).
-
-    Raises ``RuntimeError`` when the trace cannot start or stop."""
-    if not profile_dir:
-        yield
-        return
-    import jax.profiler
-
-    try:
-        jax.profiler.start_trace(str(profile_dir))
-    except Exception as exc:
-        raise RuntimeError(
-            f"profiler trace could not start in {profile_dir}: {exc}") from exc
-    try:
-        yield
-    finally:
-        try:
-            jax.profiler.stop_trace()
-        except Exception as exc:
-            raise RuntimeError(
-                f"profiler trace could not stop in {profile_dir}: {exc}"
-            ) from exc

@@ -43,13 +43,13 @@ from repro.telemetry.monitor import HealthError
 from repro.telemetry.runlog import read_runlog
 
 
-def _engine(plan=None, seed=3, temperature=None, field=None, **kw):
+def _engine(plan=None, seed=3, temperature=None, field=None,
+            cfg=IntegratorConfig(dt=2e-3, spin_alpha=0.05, lattice_gamma=1.0),
+            **kw):
     lat = simple_cubic()
     st = init_state(lat, (4, 4, 4), temperature=300.0, spin_init="helix_x",
                     key=jax.random.PRNGKey(seed))
-    return Engine(potential=HeisenbergDMIModel(d0=0.008),
-                  cfg=IntegratorConfig(dt=2e-3, spin_alpha=0.05,
-                                       lattice_gamma=1.0),
+    return Engine(potential=HeisenbergDMIModel(d0=0.008), cfg=cfg,
                   state=st, masses=jnp.asarray(lat.masses),
                   magnetic=jnp.asarray(lat.moments) > 0, cutoff=5.0,
                   capacity=8, skin=0.2, plan=plan, temperature=temperature,
@@ -236,6 +236,35 @@ def test_bad_telemetry_type_rejected():
     eng = _engine()
     with pytest.raises(TypeError, match="telemetry"):
         eng.run(10, jax.random.PRNGKey(1), chunk=10, telemetry=42)
+
+
+def test_counters_are_the_programs_own():
+    """Engine.counters(): chunks and steps run, carries (re)built from the
+    state and the table builds they ran, the carry's in-scan builds, and a
+    force call per step, per in-scan build and per restart."""
+    eng = _engine(temperature=900.0)
+    assert eng.counters() == {"chunks": 0, "steps": 0, "restarts": 1,
+                              "restart_builds": 1, "force_calls": 1,
+                              "rebuilds": 0}
+    key = jax.random.PRNGKey(1)
+    eng.run(40, key, chunk=20)
+    eng.state = eng.state._replace(pos=eng.state.pos + 0.01)  # a restart
+    eng.run(20, key, chunk=20)
+    c = eng.counters()
+    assert (c["chunks"], c["steps"], c["restarts"],
+            c["restart_builds"]) == (3, 60, 2, 2)
+    assert c["rebuilds"] == eng.n_rebuilds > 0
+    assert c["force_calls"] == c["steps"] + c["rebuilds"] + c["restarts"]
+
+
+def test_counters_count_midpoint_force_calls():
+    cfg = IntegratorConfig(dt=2e-3, midpoint=True, midpoint_iters=2)
+    eng = _engine(cfg=cfg)
+    c0 = eng.counters()
+    eng.run(10, jax.random.PRNGKey(1), chunk=5)
+    c = {k: v - c0[k] for k, v in eng.counters().items()}
+    assert c["steps"] == 10 and c["restarts"] == 0
+    assert c["force_calls"] == 10 * (1 + 2 * 2) + c["rebuilds"]
 
 
 # ---------------------------------------------------------------------------
