@@ -10,7 +10,10 @@ Two constructions:
   decomposition shards (each device owns a slab of cells), and its
   fixed-capacity output is the TPU analogue of the paper's SVE2 "Phase A
   pre-staging" (pack valid neighbors into a rectangular buffer, then the
-  compute kernel runs fully predicated over a static shape).
+  compute kernel runs fully predicated over a static shape).  The search
+  lays the 27-cell stencil's candidate ids and coordinates out once per
+  cell and gives each atom its cell's row: a gather of whole rows, where
+  fetching each candidate for each atom would be a per-element gather.
 
 Both return a ``NeighborTable`` with per-atom index lists + validity mask.
 Crystalline solids (the paper's regime) do not diffuse, so the table is
@@ -296,27 +299,33 @@ def cell_neighbor_table(
         raise ValueError(f"n_cells {n_cells} too small for the 27-stencil; "
                          "use dense_neighbor_table")
     rc = cutoff + skin
-    cx, cy, cz = n_cells
-    grid, gmask, _ = bin_atoms(pos, box, n_cells, cell_capacity)
+    grid, _, _ = bin_atoms(pos, box, n_cells, cell_capacity)
     n = pos.shape[0]
     with phase("rebuild.search"):
-        ci, cj, ck, _ = _cell_coords(pos, box, n_cells)
+        *_, flat = _cell_coords(pos, box, n_cells)
 
-        # candidates: 27 stencil cells x cell_capacity
-        offs = jnp.array([(a, b, c) for a in (-1, 0, 1) for b in (-1, 0, 1)
-                          for c in (-1, 0, 1)], dtype=jnp.int32)  # (27,3)
-        sci = (ci[:, None] + offs[None, :, 0]) % cx
-        scj = (cj[:, None] + offs[None, :, 1]) % cy
-        sck = (ck[:, None] + offs[None, :, 2]) % cz
-        cand = grid[sci, scj, sck]                # (N, 27, K)
-        cand = cand.reshape(n, -1)                # (N, 27K)
+        # All atoms of a cell share its 27-cell stencil, so the candidates'
+        # ids and coordinates are laid out once per cell, (C, 27K): on the
+        # grid padded by one periodic image, the slice at (a, b, c) brings
+        # cell (i+a-1, j+b-1, k+c-1) to (i, j, k).  Each atom then gathers
+        # its cell's whole row, not 27K scalars one at a time.
+        def stencil(x):
+            cx, cy, cz, _ = x.shape
+            xp = jnp.pad(x, ((1, 1), (1, 1), (1, 1), (0, 0)), mode="wrap")
+            return jnp.stack(
+                [xp[a:a + cx, b:b + cy, c:c + cz] for a in range(3)
+                 for b in range(3) for c in range(3)],
+                axis=3).reshape(cx * cy * cz, -1)
+
+        grid_safe = jnp.maximum(grid, 0)
+        cand = stencil(grid)[flat]                # (N, 27K)
         valid = cand >= 0
         cand_safe = jnp.where(valid, cand, 0)
         # per-component distances: an (N, 27K, 3) block would carry a minor
         # dimension of 3, which a TPU pads to 128 lanes
         d2 = None
         for c in range(3):
-            d = pos[:, c][cand_safe] - pos[:, c:c + 1]
+            d = stencil(pos[:, c][grid_safe])[flat] - pos[:, c:c + 1]
             d = d - box[c] * jnp.round(d / box[c])
             d2 = d * d if d2 is None else d2 + d * d
         good = valid & (d2 <= rc * rc) & (cand != jnp.arange(n)[:, None])
