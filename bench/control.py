@@ -52,19 +52,25 @@ def readings(root: str, workload: str, seeds: list[int], *,
         harness.use_cache(root)
     build = harness.builder(cell)
     weights = jax.device_get(build.make_weights(cell.config))
+    potential = build.make_potential(cell.config)
+    mesh = system.make_mesh(cell.config)
+    pools = [harness.make_pool(cell.config, cell.traffic, [seed], mesh)
+             for seed in seeds]
+    plan = system.make_plan(cell.config, mesh, [p[0][0] for p in pools])
     eng = None
-    for n, seed in enumerate(seeds):
+    for n, (seed, (states, keys)) in enumerate(zip(seeds, pools)):
         t0 = time.perf_counter()
-        states, keys = harness.make_pool(cell.config, cell.traffic, [seed])
-        if eng is None:
-            eng = system.make_engine(cell.config, cell.traffic,
-                                     build.make_potential(cell.config),
-                                     states[0])
+        # Engine.run restarts a swapped state on one device only, so a
+        # Sharded plan gets an engine built from each seed's state
+        if eng is None or mesh is not None:
+            eng = system.make_engine(cell.config, cell.traffic, potential,
+                                     states[0], plan)
         episodes = harness.Episodes(eng, states, keys,
                                     cell.traffic["chunk_steps"])
         c0, outs, _ = episodes.episode(0)
         cap = check.capture(episodes.states[0], c0, outs, eng._carry,
-                            episodes.keys[0], episodes.chunk_steps)
+                            episodes.keys[0], episodes.chunk_steps,
+                            cell.config.get("plan"))
         ref = check.outputs(cell, cap, jnp.float32, weights)
         prog = check.numbers(cell, cap, ref,
                              check.program_outputs(cap, ref["compared"]))

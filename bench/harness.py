@@ -4,7 +4,9 @@ comparison with the plain references, and the result line.
 Everything that belongs to one configuration, traffic mix or metric is
 found by name in files of its own:
 
-* ``BENCHMARK.json`` names the cell's configuration file and traffic;
+* ``BENCHMARK.json`` names the cell's configuration file and traffic; the
+  configuration may name the program's plan and its mesh, which spans as
+  many chips as the cell asks for (:mod:`bench.builders.system`);
 * ``bench/traffic/<traffic>.json`` holds the mix's parameters;
 * ``bench/builders/<kind>.py`` builds the program's potential for a
   configuration's ``kind``, and ``bench/reference/<kind>.py`` its plain
@@ -70,6 +72,11 @@ def load_cell(root: str, workload: str) -> Cell:
     config = _read(os.path.join(root, entry["file"]))
     traffic = _read(os.path.join(root, "bench", "traffic",
                                  f"{wl['traffic']}.json"))
+    plan = config.get("plan")
+    span = math.prod(plan["mesh"].values()) if plan else 1
+    if span != int(wl["chips"]):
+        raise ValueError(f"cell {workload} asks for {wl['chips']} chip(s) "
+                         f"and its configuration's plan spans {span}")
     return Cell(root, bench, wl, config, traffic)
 
 
@@ -128,11 +135,14 @@ def annotate(name: str):
     return jax.profiler.TraceAnnotation(name)
 
 
-def make_pool(cfg: dict, traffic: dict, seeds) -> tuple[list, list]:
+def make_pool(cfg: dict, traffic: dict, seeds, mesh=None
+              ) -> tuple[list, list]:
     """Each episode seed's state and the keys of its chunks, committed to
-    the state's device like every other input (so that the chunk program
-    is compiled for one signature only)."""
+    the state's device like every other input, or replicated over the
+    plan's ``mesh`` (so that the chunk program is compiled for one
+    signature only)."""
     import jax
+    from jax.sharding import NamedSharding, PartitionSpec
 
     from bench.builders.system import make_state, seed_key
 
@@ -144,7 +154,8 @@ def make_pool(cfg: dict, traffic: dict, seeds) -> tuple[list, list]:
         state = make_state(cfg, traffic, seed)
         k = jax.device_put(jax.random.split(
             jax.random.fold_in(seed_key(seed), 1), ep // chunk),
-            state.pos.sharding)
+            state.pos.sharding if mesh is None
+            else NamedSharding(mesh, PartitionSpec()))
         states.append(state)
         keys.append([k[c] for c in range(k.shape[0])])
     return states, keys
@@ -152,18 +163,30 @@ def make_pool(cfg: dict, traffic: dict, seeds) -> tuple[list, list]:
 
 class Episodes:
     """The traffic of a coupled cell: a fixed pool of episodes, each a
-    seeded state and its chunks' keys (:func:`make_pool`), replayed through
-    the engine's own restart path, each chunk one ``Engine.run`` call.  The
-    window plays the pool in ``order``.  Every episode is the same sequence
-    of work, whatever the speed of the program."""
+    seeded state and its chunks' keys (:func:`make_pool`), each chunk one
+    ``Engine.run`` call.  An episode restarts through the engine's own
+    restart path (``state`` swapped, ``run(0)``: a table build and a force
+    evaluation).  The window plays the pool in ``order``.  Every episode is
+    the same sequence of work, whatever the speed of the program.
 
-    def __init__(self, eng, states, keys, chunk_steps: int, order=None):
+    ``carries`` is a stand-in, to be deleted once ``Engine.run`` restarts
+    a swapped state on the ``Sharded`` plan, which it does not yet: there,
+    each state's carry is built in set-up by an engine of its own
+    (:func:`run_cell`) and an episode restarts by taking it up, so the
+    window holds no restart build or force call on that plan."""
+
+    def __init__(self, eng, states, keys, chunk_steps: int, order=None,
+                 carries=None):
         self.eng, self.states, self.keys = eng, states, keys
         self.chunk_steps = chunk_steps
         self.order = list(range(len(states))) if order is None else order
+        self.carries = carries
 
     def restart(self, m: int):
         with annotate("bench.restart"):
+            if self.carries is not None:
+                self.eng._carry = self.carries[m]
+                return
             self.eng.state = self.states[m]
             self.eng.run(0, self.keys[m][0], chunk=self.chunk_steps)
 
@@ -196,14 +219,16 @@ class Episodes:
         import jax
 
         eng = self.eng
-        reb0 = eng.n_rebuilds
+        counts = []     # each episode's build counts, read after the window
         passes = nonfinite = 0
+        built0 = eng.counters()["restart_builds"]
         t0 = time.perf_counter()
         with annotate(WINDOW):
             while True:
                 for m in self.order:
                     c0, outs, bad = self.episode(m)
                     nonfinite += bad
+                    counts.append((c0.n_rebuilds, outs[-1]["n_rebuilds"]))
                 passes += 1
                 if time.perf_counter() - t0 >= seconds:
                     break
@@ -212,8 +237,10 @@ class Episodes:
         restarts = passes * len(self.order)
         chunks = passes * sum(len(self.keys[m]) for m in self.order)
         return {"steps": chunks * self.chunk_steps, "restarts": restarts,
+                "restart_builds": eng.counters()["restart_builds"] - built0,
                 "chunks": chunks, "nonfinite_chunks": nonfinite,
-                "window_s": elapsed, "rebuilds": eng.n_rebuilds - reb0,
+                "window_s": elapsed,
+                "rebuilds": sum(int(b) - int(a) for a, b in counts),
                 "last": m, "carries": (c0, outs, eng._carry)}
 
 
@@ -271,12 +298,19 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     potential = build.make_potential(cell.config)
     jax.block_until_ready(getattr(potential, "params", None))
     phase("weights")
-    states, keys = make_pool(cell.config, cell.traffic, seeds)
+    mesh = system.make_mesh(cell.config)
+    states, keys = make_pool(cell.config, cell.traffic, seeds, mesh)
+    plan = system.make_plan(cell.config, mesh, states)
     phase("state")
     eng = system.make_engine(cell.config, cell.traffic, potential,
-                             states[order[0]])
-    episodes = Episodes(eng, states, keys, cell.traffic["chunk_steps"], order)
-    jax.block_until_ready(eng._carry)
+                             states[order[0]], plan)
+    carries = None if mesh is None else [
+        eng._carry if m == order[0] else system.make_engine(
+            cell.config, cell.traffic, potential, s, plan)._carry
+        for m, s in enumerate(states)]
+    episodes = Episodes(eng, states, keys, cell.traffic["chunk_steps"], order,
+                        carries)
+    jax.block_until_ready((eng._carry, carries))
     phase("engine")
     m = order[0]
     episodes.restart(m)
@@ -312,7 +346,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     rec.update(atoms=n_atoms, chips=chips, setup_s=setup_s,
                compiles=compiles, device_kind=kind, platform=platform,
                config=cell.config, traffic=cell.traffic,
-               force_calls=rec["steps"] + rec["rebuilds"] + rec["restarts"])
+               force_calls=rec["steps"] + rec["rebuilds"]
+               + rec["restart_builds"])
     print(f"window: {rec['steps']} steps in {rec['chunks']} chunks, "
           f"{rec['restarts']} episode restarts, {rec['rebuilds']} in-scan "
           f"rebuilds, {rec['window_s']:.3f} s, {compiles} compiles",
@@ -327,8 +362,9 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     # the program's state freed
     last = rec.pop("last")
     cap = check.capture(episodes.states[last], *rec.pop("carries"),
-                        episodes.keys[last], episodes.chunk_steps)
-    del episodes, eng, potential
+                        episodes.keys[last], episodes.chunk_steps,
+                        cell.config.get("plan"))
+    del episodes, eng, potential, carries
     weights = jax.device_get(build.make_weights(cell.config))
     t_ref = time.perf_counter()
     ref = check.outputs(cell, cap, jax.numpy.float32, weights)

@@ -5,6 +5,12 @@ half-step (k1), half kick, spin half-step about H_eff with the stochastic
 LLG field (k2), drift, new (E, F, H_eff), spin half-step (k3), half kick,
 lattice Langevin half-step (k5).  Random numbers are drawn per row of the
 program's atom layout, so the layout (``perm``: row -> atom) is an input.
+
+On a domain-decomposed layout (``blocks = (devices, slot_shape)``) each
+device draws over its own block of cell slots, of ``slot_shape``, from the
+step key folded with its linear device index, and only then splits k1..k5;
+``perm`` lists the atom of every slot, device after device, -1 where the
+slot is empty.
 """
 from __future__ import annotations
 
@@ -17,7 +23,7 @@ from bench import units
 
 class Step:
     def __init__(self, masses, magnetic, types, box, dt, moment,
-                 lattice_gamma, spin_alpha):
+                 lattice_gamma, spin_alpha, blocks=None):
         types = np.asarray(types)
         m = jnp.asarray(np.asarray(masses, np.float32)[types])
         mag = jnp.asarray(np.asarray(magnetic, bool)[types])[:, None]
@@ -26,20 +32,31 @@ class Step:
         c1 = float(np.exp(-lattice_gamma * half))
         gp = units.GYRO / (1.0 + spin_alpha ** 2)
 
-        def noise(key, perm, like):
-            z = jax.random.normal(key, like.shape, like.dtype)
-            return jnp.zeros_like(z).at[perm].set(z)
+        def noise(key, which, perm, like):
+            """Normal numbers of key ``which`` of the step's five, in atom
+            order."""
+            if blocks is None:
+                k = jax.random.split(key, 5)[which]
+                z = jax.random.normal(k, like.shape, like.dtype)
+                return jnp.zeros_like(z).at[perm].set(z)
+            devices, shape = blocks
+            z = jnp.concatenate([jax.random.normal(
+                jax.random.split(jax.random.fold_in(key, d), 5)[which],
+                tuple(shape) + like.shape[1:], like.dtype).reshape(
+                    -1, like.shape[1]) for d in range(devices)])
+            rows = jnp.where(perm >= 0, perm, like.shape[0])
+            return jnp.zeros_like(like).at[rows].set(z, mode="drop")
 
-        def thermostat(vel, key, perm, temp):
+        def thermostat(vel, key, which, perm, temp):
             sigma = jnp.sqrt(units.KB * temp * (1.0 - c1 ** 2)
                              / (m * units.MVV2E))
-            return c1 * vel + sigma[:, None] * noise(key, perm, vel)
+            return c1 * vel + sigma[:, None] * noise(key, which, perm, vel)
 
-        def spin_half(spin, heff, key, perm, temp):
+        def spin_half(spin, heff, key, which, perm, temp):
             b = heff / (moment * units.MU_B)
             sig = jnp.sqrt(2.0 * spin_alpha * units.KB * temp
                            / (units.GYRO * moment * units.MU_B * half))
-            b = b + sig * noise(key, perm, b)
+            b = b + sig * noise(key, which, perm, b)
             omega = gp * b + gp * spin_alpha * jnp.cross(spin, b)
             theta = jnp.linalg.norm(omega, axis=-1, keepdims=True)
             axis = omega / jnp.where(theta > 0, theta, 1.0)
@@ -50,20 +67,18 @@ class Step:
             return jnp.where(mag, new, spin)
 
         def before(pos, vel, spin, force, heff, key, perm, temp):
-            k1, k2, _, _, _ = jax.random.split(key, 5)
             temp = jnp.maximum(temp, 0.0)
-            vel = thermostat(vel, k1, perm, temp)
+            vel = thermostat(vel, key, 0, perm, temp)
             vel = vel + half * force / m[:, None] * units.FORCE2ACC
-            spin = spin_half(spin, heff, k2, perm, temp)
+            spin = spin_half(spin, heff, key, 1, perm, temp)
             pos = pos + dt * vel
             return pos - box * jnp.floor(pos / box), vel, spin
 
         def after(vel, spin, force, heff, key, perm, temp):
-            _, _, k3, _, k5 = jax.random.split(key, 5)
             temp = jnp.maximum(temp, 0.0)
-            spin = spin_half(spin, heff, k3, perm, temp)
+            spin = spin_half(spin, heff, key, 2, perm, temp)
             vel = vel + half * force / m[:, None] * units.FORCE2ACC
-            return thermostat(vel, k5, perm, temp), spin
+            return thermostat(vel, key, 4, perm, temp), spin
 
         def moved(pos, r0, limit):
             d = pos - r0

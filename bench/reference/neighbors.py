@@ -98,18 +98,63 @@ def bf16_table(r0, box, r: float, capacity: int):
     return _pack(i[keep], j[keep], np.asarray(r0).shape[0], capacity)
 
 
-def cells(pos, box, r: float, dtype=np.float64):
-    """Each atom's bin on the grid of cells at least ``r`` wide (flat index,
-    x slowest), with the fractional position taken in ``dtype``, and its
+def bins(pos, box, n, dtype=np.float64):
+    """Each atom's bin on a grid of ``n`` cells a dimension (flat index, x
+    slowest), with the fractional position taken in ``dtype``, and its
     distance [Å] to the nearest cell face."""
     box64 = np.asarray(box, np.float64)
-    n = np.maximum(np.floor(box64 / r), 1).astype(np.int64)
+    n = np.asarray(n, np.int64)
     u = (np.asarray(pos, np.float64).astype(dtype) / box64.astype(dtype)
          * n.astype(dtype)).astype(np.float64)
     c = np.clip(np.floor(u).astype(np.int64), 0, n - 1)
     flat = (c[:, 0] * n[1] + c[:, 1]) * n[2] + c[:, 2]
     face = np.min(np.abs(u - np.round(u)) * (box64 / n), axis=-1)
     return flat, face
+
+
+def cells(pos, box, r: float, dtype=np.float64):
+    """:func:`bins` on the grid of cells at least ``r`` wide."""
+    n = np.maximum(np.floor(np.asarray(box, np.float64) / r), 1)
+    return bins(pos, box, n.astype(np.int64), dtype)
+
+
+def pack(rank, pos, box, grid, dtype=np.float64) -> np.ndarray:
+    """Atom ids in the cell slots of ``grid`` = (CX, CY, CZ, K), flat, -1
+    where empty: each atom in its bin at ``pos``, a bin's atoms in the
+    order of ``rank``."""
+    rank = np.asarray(rank)
+    n, k = rank.size, int(grid[3])
+    flat, _ = bins(pos, box, grid[:3], dtype)
+    order = np.lexsort((rank, flat))
+    counts = np.bincount(flat, minlength=int(np.prod(grid[:3])))
+    if counts.max() > k:
+        raise ValueError(f"a cell holds {counts.max()} atoms, over {k}")
+    start = np.concatenate([[0], np.cumsum(counts)[:-1]])
+    fo = flat[order]
+    out = np.full(int(np.prod(grid)), -1, np.int64)
+    out[fo * k + np.arange(n) - start[fo]] = order
+    return out
+
+
+def slot_errors(rows, slots, grid, pos, box, r: float, face: float) -> int:
+    """Atoms held in another cell than their bin at ``pos``, where ``rows``
+    holds the atom of each listed slot of the cell grid ``grid`` = (CX,
+    CY, CZ, K) (-1 where empty) and ``slots`` each one's flat place in it.
+    Atoms within ``face`` of a cell face are left out: there the rounding
+    of a position decides their cell.  A layout that does not hold every
+    atom once, or whose cells are narrower than ``r``, counts every
+    atom."""
+    rows = np.asarray(rows)
+    n = np.asarray(pos).shape[0]
+    held = rows >= 0
+    ids = rows[held]
+    if (ids.size != n or not np.array_equal(np.sort(ids), np.arange(n))
+            or np.any(np.asarray(box, np.float64)
+                      / np.asarray(grid[:3]) < r)):
+        return n
+    flat, dist = bins(pos, box, grid[:3])
+    cell = np.asarray(slots)[held] // int(grid[3])
+    return int(np.count_nonzero((flat[ids] != cell) & (dist[ids] >= face)))
 
 
 def relayout(prev, pos, box, r: float, dtype=np.float64) -> np.ndarray:

@@ -18,6 +18,12 @@ for p in (REPO, os.path.join(REPO, "src")):
 SMALL = {"cells": 4, "neighbor": {"capacity": 72, "skin": 0.5,
                                   "cell_capacity": 40}}
 SHORT = {"episode_steps": 20, "chunk_steps": 10}
+# 5^3 B20 cells (1,000 atoms): the smallest cubic box whose cell grid the
+# Sharded plan splits over a 2x2 mesh (four cells a side, two a device);
+# the plan resolves its grid and cell capacity itself
+SHARDED = {"cells": 5, "plan": {"kind": "sharded",
+                                "mesh": {"sx": 2, "sy": 2},
+                                "axis_map": ["sx", "sy", None]}}
 
 
 def _small_root(path, bench: dict) -> str:
@@ -50,3 +56,36 @@ def small_root(tmp_path):
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     return _small_root(tmp_path, bench)
+
+
+def sharded_root(path, config: str, traffic: dict | None = None) -> str:
+    """A root at ``path`` whose one cell, ``<config>-2x2``, runs a small
+    copy of ``config`` on a 2x2 ``Sharded`` plan over four chips, with
+    :data:`SHORT` episodes updated by ``traffic``, and the limits of
+    ``config``."""
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = dict([c for c in bench["configs"] if c["name"] == config][0])
+    name = f"{config}-2x2"
+    entry.update(name=name, file=f"bench/configs/{name}.json")
+    bench["configs"] = [entry]
+    bench["workloads"] = [{"name": name, "config": name,
+                           "traffic": "fc-hold-ep100", "chips": 4,
+                           "why": "test"}]
+    root = _small_root(path, {**bench, "configs": []})
+    with open(os.path.join(REPO, "bench", "configs", f"{config}.json")) as f:
+        cfg = json.load(f)
+    cfg.update(SMALL, name=name, **SHARDED)
+    with open(os.path.join(root, entry["file"]), "w") as f:
+        json.dump(cfg, f)
+    shutil.copy(os.path.join(root, "bench", "limits", f"{config}.json"),
+                os.path.join(root, "bench", "limits", f"{name}.json"))
+    p = os.path.join(root, "bench", "traffic", "fc-hold-ep100.json")
+    with open(p) as f:
+        mix = json.load(f)
+    mix.update(traffic or {})
+    with open(p, "w") as f:
+        json.dump(mix, f)
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+    return root

@@ -4,7 +4,11 @@ The control is the plain reference in bfloat16 put in the program's place;
 the faults are planted in the program underneath a whole run at a small
 size on the CPU: a step that returns its state unchanged, a force altered
 where it is produced, a neighbour table that drops a pair, an in-scan
-rebuild that never fires, and rows laid out apart from the cell sort.
+rebuild that never fires, and rows laid out apart from the cell sort; and
+on a 2x2 ``Sharded`` plan (four CPU devices, a process of its own): the
+halo's contributions zeroed in the adjoint fold, the forward halo exchange
+taken from each device's own block, every device's random numbers drawn
+from device 0's key, and the in-scan migration skipped.
 """
 import json
 import os
@@ -12,6 +16,8 @@ import os
 import pytest
 
 from bench import check, control, harness
+from bench.tests.conftest import sharded_root
+from bench.tests.sharded_run import run
 
 SEED = 11
 
@@ -118,6 +124,29 @@ def test_rows_laid_out_apart_from_the_cell_sort(small_root, monkeypatch):
     res = _run(small_root)
     assert res["correct"] is False
     assert res["checks"]["layout_rows"]["value"] >= 1
+
+
+@pytest.fixture(scope="module")
+def sharded_cache(tmp_path_factory):
+    """One compile cache for the sharded faults' runs, which share every
+    program that their fault leaves unchanged."""
+    return str(tmp_path_factory.mktemp("compile_cache"))
+
+
+@pytest.mark.parametrize("fault, episodes, number", [
+    ("halo_zeroed", None, "restart_F"),
+    ("halo_local", None, "table_pairs"),
+    ("device0_key", None, "chunk_vel"),
+    # the migration only runs in a rebuild, which needs longer episodes
+    ("no_migration", {"episode_steps": 60, "chunk_steps": 10},
+     "layout_rows"),
+])
+def test_sharded_path_broken(tmp_path, sharded_cache, fault, episodes,
+                             number):
+    root = sharded_root(tmp_path, "fege-heisenberg-dmi", episodes)
+    res = run(root, "fege-heisenberg-dmi-2x2", fault, sharded_cache)
+    assert res["correct"] is False
+    assert res["checks"][number]["value"] > res["checks"][number]["limit"]
 
 
 @pytest.mark.parametrize("name", check.NAMES)

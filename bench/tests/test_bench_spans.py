@@ -87,7 +87,8 @@ def test_engine_programs_and_run_name_every_sub_phase_and_span():
     states, keys = harness.make_pool(cell.config, cell.traffic, [0])
     eng = system.make_engine(cell.config, cell.traffic,
                              harness.builder(cell).make_potential(
-                                 cell.config), states[0])
+                                 cell.config), states[0],
+                             system.make_plan(cell.config, None, states))
     eng.run(10, keys[0][0], chunk=10)
     with tempfile.TemporaryDirectory() as d:
         trace.start(d)
